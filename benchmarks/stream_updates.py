@@ -79,7 +79,7 @@ def _bench_delta(csv: Csv, tag: str, g: Graph, plan0, prev_ranks,
                  delta: GraphDelta, cfg: PlanConfig, *,
                  deep: bool = True) -> None:
     k = plan0.partitioning.num_partitions
-    dirty = len(delta.dirty_partitions(cfg.part_size))
+    dirty = len(delta.dirty_partitions(plan0.part_size))
     g2 = apply_delta(g, delta)
 
     # ---- warm: incremental plan patch

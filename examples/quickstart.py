@@ -8,8 +8,8 @@ Session per engine: the session resolves the graph's ``GraphPlan``
 §IV-B) through the process-level plan cache, runs 20 PageRank
 iterations, checks the engines agree, and prints the paper's headline
 statistics: compression ratio r, modeled bytes per edge (eqs. 3-5)
-and measured per-iteration time.  The pcpm and pcpm_pallas plans share
-one PNG build, and re-opening a session costs zero preprocessing.
+and measured per-iteration time.  Plans of one (graph, part_size)
+share one PNG build, and re-opening a session costs zero preprocessing.
 
 ``--serve`` continues into the serving layer: ``sess.serve()`` hands
 back a continuous-batching SlotScheduler (DESIGN.md §7) answering

@@ -54,7 +54,7 @@ class EngineConfig:
     """
     # plan layer
     method: str = "pcpm"
-    part_size: int = 65536
+    part_size: Optional[int] = None       # None: the backend derives it
     num_shards: Optional[int] = None      # sharding backends; None = all
     gather_block: int = DEFAULT_GATHER_BLOCK
     two_phase: bool = False               # rejected by Session (fused)

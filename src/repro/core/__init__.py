@@ -1,4 +1,4 @@
-from .partition import Partitioning, partition_for_vmem
+from .partition import Partitioning
 from .png import (PNGLayout, BlockedPNG, GatherSchedule, build_png,
                   block_png, build_gather_schedule,
                   flat_gather_schedule)
@@ -16,7 +16,7 @@ from .pagerank import (pagerank, pagerank_reference, PageRankResult,
 from . import comm_model
 
 __all__ = [
-    "Partitioning", "partition_for_vmem", "PNGLayout", "BlockedPNG",
+    "Partitioning", "PNGLayout", "BlockedPNG",
     "GatherSchedule", "build_png", "block_png", "build_gather_schedule",
     "flat_gather_schedule",
     "GraphPlan", "PlanConfig", "build_plan", "clear_plan_cache",

@@ -5,9 +5,12 @@ Every SpMV engine registers ONE ``Backend`` entry:
 
 - ``build_plan(g, cfg) -> GraphPlan``: the host-side preprocessing
   (edge sorts, PNG build, schedules) for that method;
-- ``spmv_fn(plan) -> (x -> A^T x)``: a pure traceable closure over the
-  plan's device-resident streams — what the fused ``lax.while_loop``
-  drivers, the chunk steppers and AOT compilation consume;
+- ``spmv_fn(plan) -> (x -> A^T x)``: a ``jax.tree_util.Partial``
+  whose leaves are the plan's device-resident streams — what the fused
+  ``lax.while_loop`` drivers, the chunk steppers and AOT compilation
+  consume.  Consumers pass it to their jitted loops as an ARGUMENT:
+  a closed-over device array is baked into the program as a constant,
+  which at graph500-22 would put ~0.4 GB of streams into the HLO;
 - capability flags (``supports_sharding``, ``supports_aot``,
   ``multi_vector``, ``supports_two_phase``) that consumers branch on
   instead of comparing method strings.
@@ -21,6 +24,7 @@ every consumer of the same plan.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Optional
 
 import numpy as np
@@ -29,7 +33,7 @@ import jax.numpy as jnp
 
 from ..graphs.formats import Graph
 from .partition import Partitioning
-from .plan import GraphPlan, PlanConfig, shared_png
+from .plan import DEFAULT_PART_SIZE, GraphPlan, PlanConfig, shared_png
 from .png import (GatherSchedule, block_png, build_gather_schedule,
                   flat_gather_schedule)
 
@@ -67,6 +71,10 @@ class Backend:
     # it fall back to a full rebuild on every delta.
     patch_plan: Optional[
         Callable[[GraphPlan, Graph, "object"], GraphPlan]] = None
+    # ``(g, requested) -> part_size``: resolves ``part_size=None`` and
+    # refuses a size the backend cannot run
+    part_size: Callable[[Graph, Optional[int]], int] = (
+        lambda g, requested: requested or DEFAULT_PART_SIZE)
 
     @property
     def supports_two_phase(self) -> bool:
@@ -125,7 +133,7 @@ def check_device_count(num_shards: int) -> None:
 
 
 def resolve_engine(g: Graph, *, method: str, sharded: bool,
-                   part_size: int, num_shards: Optional[int],
+                   part_size: Optional[int], num_shards: Optional[int],
                    engine=None):
     """Shared engine resolution of the serving front-ends
     (``PageRankServer``, ``SlotScheduler``): construct through the
@@ -143,10 +151,11 @@ def resolve_engine(g: Graph, *, method: str, sharded: bool,
 
 
 def normalize_config(g: Graph, cfg: PlanConfig) -> PlanConfig:
-    """Canonical cache key: resolve ``num_shards=None`` to the device
-    count for sharding backends (validating the bound), and blank the
-    knobs a backend ignores (sharding fields, gather_block) so configs
-    differing only in irrelevant knobs share one plan."""
+    """Canonical cache key: resolve ``part_size=None`` through the
+    backend and ``num_shards=None`` to the device count for sharding
+    backends (validating both), and blank the knobs a backend ignores
+    (sharding fields, gather_block) so configs differing only in
+    irrelevant knobs share one plan."""
     from .plan import DEFAULT_GATHER_BLOCK
     backend = get_backend(cfg.method)
     if cfg.reorder != "none":
@@ -156,6 +165,9 @@ def normalize_config(g: Graph, cfg: PlanConfig) -> PlanConfig:
                 f"unknown reorder {cfg.reorder!r}; valid: "
                 f"{available_orderings()}")
     kw = {}
+    part_size = backend.part_size(g, cfg.part_size)
+    if part_size != cfg.part_size:
+        kw["part_size"] = part_size
     if backend.supports_sharding:
         shards = cfg.num_shards or jax.device_count()
         check_device_count(shards)
@@ -290,12 +302,23 @@ def _sched_device(plan: GraphPlan):
     return dev
 
 
-def _spmv_pdpr(plan: GraphPlan):
+def _gather(eui, ps, pe, pd, x, *, num_nodes: int, block: int):
     from .spmv import pcpm_gather_blocked
-    eui, ps, pe, pd = _sched_device(plan)
-    n, blk = plan.num_nodes, plan.schedule.block
-    return lambda x: pcpm_gather_blocked(x, eui, ps, pe, pd,
-                                         num_nodes=n, block=blk)
+    return pcpm_gather_blocked(x, eui, ps, pe, pd, num_nodes=num_nodes,
+                               block=block)
+
+
+def _streams_fn(fn, *streams, **static):
+    """``x -> fn(*streams, x, **static)`` as a pytree whose leaves are
+    the streams (see the module docstring)."""
+    return jax.tree_util.Partial(functools.partial(fn, **static),
+                                 *streams)
+
+
+def _spmv_pdpr(plan: GraphPlan):
+    return _streams_fn(_gather, *_sched_device(plan),
+                       num_nodes=plan.num_nodes,
+                       block=plan.schedule.block)
 
 
 # ---------------------------------------------------------------------------
@@ -331,13 +354,19 @@ def _bvgas_device(plan: GraphPlan):
     return dev
 
 
+def _scatter_gather(src, eui, ps, pe, pd, x, *, scatter, num_nodes: int,
+                    block: int):
+    """bvgas and pcpm alike: the method's scatter into bins, then the
+    blocked gather."""
+    return _gather(eui, ps, pe, pd, scatter(src, x), num_nodes=num_nodes,
+                   block=block)
+
+
 def _spmv_bvgas(plan: GraphPlan):
-    from .spmv import bvgas_scatter, pcpm_gather_blocked
-    src = _bvgas_device(plan)
-    eui, ps, pe, pd = _sched_device(plan)
-    n, blk = plan.num_nodes, plan.schedule.block
-    return lambda x: pcpm_gather_blocked(
-        bvgas_scatter(src, x), eui, ps, pe, pd, num_nodes=n, block=blk)
+    from .spmv import bvgas_scatter
+    return _streams_fn(_scatter_gather, _bvgas_device(plan),
+                       *_sched_device(plan), scatter=bvgas_scatter,
+                       num_nodes=plan.num_nodes, block=plan.schedule.block)
 
 
 def _phases_bvgas(plan: GraphPlan):
@@ -372,11 +401,10 @@ def _pcpm_device(plan: GraphPlan):
 
 
 def _spmv_pcpm(plan: GraphPlan):
-    from .spmv import pcpm_gather_blocked, pcpm_scatter
-    upd, eui, ps, pe, pd = _pcpm_device(plan)
-    n, blk = plan.num_nodes, plan.schedule.block
-    return lambda x: pcpm_gather_blocked(
-        pcpm_scatter(upd, x), eui, ps, pe, pd, num_nodes=n, block=blk)
+    from .spmv import pcpm_scatter
+    return _streams_fn(_scatter_gather, *_pcpm_device(plan),
+                       scatter=pcpm_scatter, num_nodes=plan.num_nodes,
+                       block=plan.schedule.block)
 
 
 def _phases_pcpm(plan: GraphPlan):
@@ -391,6 +419,20 @@ def _phases_pcpm(plan: GraphPlan):
 # ---------------------------------------------------------------------------
 # pcpm_pallas — the Pallas gather kernel path (kernels/pcpm_spmv)
 # ---------------------------------------------------------------------------
+def _pallas_part_size(g: Graph, requested: Optional[int]) -> int:
+    """The largest power-of-two partition whose kernel working set
+    fits VMEM.  Sizes past the graph's (power-of-two) node count are
+    one partition either way and are cut to it; an explicit size that
+    still cannot fit raises here, at plan build."""
+    from ..kernels.pcpm_spmv.kernel import check_part_size, max_part_size
+    whole = 1 << max(g.num_nodes - 1, 7).bit_length()
+    if requested is None:
+        return min(max_part_size(), whole)
+    requested = min(requested, whole)
+    check_part_size(requested)
+    return requested
+
+
 def _build_pcpm_pallas(g: Graph, cfg: PlanConfig) -> GraphPlan:
     png = shared_png(g, cfg.part_size)
     return GraphPlan(png=png, blocked=block_png(png),
@@ -408,8 +450,7 @@ def _packed_device(plan: GraphPlan):
 
 def _spmv_pcpm_pallas(plan: GraphPlan):
     from ..kernels.pcpm_spmv import pcpm_spmv_pallas
-    packed = _packed_device(plan)
-    return lambda x: pcpm_spmv_pallas(packed, x)
+    return jax.tree_util.Partial(pcpm_spmv_pallas, _packed_device(plan))
 
 
 # ---------------------------------------------------------------------------
@@ -431,13 +472,13 @@ def _spmv_pcpm_sharded(plan: GraphPlan):
         spmv = pcpm_all_to_all_spmv(plan.sharded, sharded_mesh(plan, axis),
                                     axis)
         plan._device[key] = spmv
-    n, n_pad = plan.num_nodes, plan.sharded.padded_nodes
+    return _streams_fn(_padded_apply, spmv, num_nodes=plan.num_nodes,
+                       padded=plan.sharded.padded_nodes)
 
-    def fn(x):
-        width = ((0, n_pad - n),) + ((0, 0),) * (x.ndim - 1)
-        return spmv(jnp.pad(x, width))[:n]
 
-    return fn
+def _padded_apply(spmv, x, *, num_nodes: int, padded: int):
+    width = ((0, padded - num_nodes),) + ((0, 0),) * (x.ndim - 1)
+    return spmv(jnp.pad(x, width))[:num_nodes]
 
 
 # ---------------------------------------------------------------------------
@@ -476,7 +517,8 @@ for _backend in (
             phase_fns=_phases_pcpm, patch_plan=_patch_pcpm,
             supports_push_query=True),
     Backend("pcpm_pallas", _build_pcpm_pallas, _spmv_pcpm_pallas,
-            patch_plan=_patch_pcpm_pallas, supports_push_query=True),
+            patch_plan=_patch_pcpm_pallas, supports_push_query=True,
+            part_size=_pallas_part_size),
     # pcpm_sharded has no patcher: shard-local receive buffers and the
     # all-to-all send schedule are global layouts (a delta anywhere can
     # grow any shard's wire stream), so deltas fall back to a full

@@ -34,7 +34,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..graphs.formats import Graph
 from .png import flat_gather_schedule
@@ -179,6 +178,14 @@ def build_sharded_png(g: Graph, num_shards: int, *,
 
 
 # --------------------------------------------------------------- engines
+def _place(mesh: Mesh, axis: str, *arrays) -> tuple:
+    """Put each host array on the mesh, its leading (shard) axis
+    split over ``axis`` — once, so programs take them as arguments and
+    never carry them as constants."""
+    return tuple(jax.device_put(a, NamedSharding(
+        mesh, P(axis, *([None] * (np.ndim(a) - 1))))) for a in arrays)
+
+
 def _scatter_all_to_all(x_l, send_l, axis, *, num_shards, shard_size,
                         u_max):
     """Shard-local scatter + wire phase: gather this shard's dedup send
@@ -205,13 +212,11 @@ def pcpm_all_to_all_spmv(layout: ShardedPNG, mesh: Mesh, axis: str, *,
     s, u = layout.num_shards, layout.send_ids.shape[2]
     ssz = layout.shard_size
     blk = layout.gather_block
-    send_ids = jnp.asarray(layout.send_ids)     # (S, S, U)
-    edge_upd = jnp.asarray(layout.edge_upd)     # (S, E)
-    edge_dst = jnp.asarray(layout.edge_dst)     # (S, E)
-    eui = jnp.asarray(layout.eui_padded)        # (S, Mp)
-    ps = jnp.asarray(layout.piece_start)        # (S, P0)
-    pe = jnp.asarray(layout.piece_end)          # (S, P0)
-    pd = jnp.asarray(layout.piece_dst)          # (S, P0)
+    # (S, S, U) send ids, (S, E) edge streams, (S, Mp)/(S, P0) schedule
+    streams = _place(mesh, axis, layout.send_ids, layout.edge_upd,
+                     layout.edge_dst, layout.eui_padded,
+                     layout.piece_start, layout.piece_end,
+                     layout.piece_dst)
     vec = P(axis)
     mat = P(axis, None)
 
@@ -226,19 +231,19 @@ def pcpm_all_to_all_spmv(layout: ShardedPNG, mesh: Mesh, axis: str, *,
         y = jax.ops.segment_sum(vals, ed_l[0], num_segments=ssz + 1)
         return y[:ssz]
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(vec, P(axis, None, None), mat, mat, mat,
-                             mat, mat, mat),
-                   out_specs=vec)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(vec, P(axis, None, None), mat, mat, mat,
+                                 mat, mat, mat),
+                       out_specs=vec, check_vma=False)
 
     @jax.jit
-    def spmv(x):
+    def spmv(streams, x):
         squeeze = x.ndim == 1
         xs = x[:, None] if squeeze else x
-        y = fn(xs, send_ids, edge_upd, edge_dst, eui, ps, pe, pd)
+        y = fn(xs, *streams)
         return y[:, 0] if squeeze else y
 
-    return spmv
+    return jax.tree_util.Partial(spmv, streams)
 
 
 def edge_cut_spmv(g: Graph, num_shards: int, mesh: Mesh, axis: str):
@@ -285,8 +290,8 @@ def edge_cut_spmv(g: Graph, num_shards: int, mesh: Mesh, axis: str):
                                 num_segments=shard_size + 1)
         return y[:shard_size]
 
-    fn = shard_map(local, mesh=mesh, in_specs=(vec, mat, mat),
-                   out_specs=vec)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(vec, mat, mat),
+                       out_specs=vec, check_vma=False)
 
     @jax.jit
     def spmv(x):
@@ -306,16 +311,18 @@ def pad_to_shards(x: np.ndarray, layout: ShardedPNG) -> np.ndarray:
 
 
 # ----------------------------------------------- fused sharded iteration
-def _shard_streams(layout: ShardedPNG):
-    """Device copies of the static layout streams plus the pad-row
-    mask — the per-shard constants every shard_map'd iteration loop
-    (fused batch loop and serving chunk stepper alike) closes over."""
+def _shard_streams(layout: ShardedPNG, mesh: Mesh, axis: str):
+    """The static layout streams plus the pad-row mask, each placed
+    once with its leading axis split over the mesh — the per-shard
+    data every shard_map'd iteration loop (fused batch loop and
+    serving chunk stepper alike) takes as arguments.  Passed as
+    arguments, not closed over: a closed-over array would be baked
+    into the program as a constant on one device."""
     mask_host = np.zeros(layout.padded_nodes, dtype=np.float32)
     mask_host[:layout.num_nodes] = 1.0
-    return (jnp.asarray(layout.send_ids), jnp.asarray(layout.eui_padded),
-            jnp.asarray(layout.piece_start),
-            jnp.asarray(layout.piece_end),
-            jnp.asarray(layout.piece_dst), jnp.asarray(mask_host))
+    return _place(mesh, axis, layout.send_ids, layout.eui_padded,
+                  layout.piece_start, layout.piece_end, layout.piece_dst,
+                  mask_host)
 
 
 def _local_gather_spmv(layout: ShardedPNG, axis: str, send_l, eui_l,
@@ -362,7 +369,6 @@ def sharded_power_iteration(layout: ShardedPNG, mesh: Mesh, axis: str,
     """
     if dangling not in ("none", "redistribute"):
         raise ValueError(f"unknown dangling policy {dangling!r}")
-    send_ids, eui, ps, pe, pd, mask = _shard_streams(layout)
     vec = P(axis)
     state_spec = P(axis, None) if multi else P(axis)
 
@@ -411,19 +417,19 @@ def sharded_power_iteration(layout: ShardedPNG, mesh: Mesh, axis: str,
             cond, body, (jnp.int32(0), pr, residuals0, jnp.bool_(False)))
         return pr, it, residuals
 
-    fn = shard_map(local_run, mesh=mesh,
-                   in_specs=(state_spec, vec, state_spec, vec,
-                             P(axis, None, None), P(axis, None),
-                             P(axis, None), P(axis, None),
-                             P(axis, None)),
-                   out_specs=(state_spec, P(), P()),
-                   check_rep=False)
+    fn = jax.shard_map(local_run, mesh=mesh,
+                       in_specs=(state_spec, vec, state_spec, vec,
+                                 P(axis, None, None), P(axis, None),
+                                 P(axis, None), P(axis, None),
+                                 P(axis, None)),
+                       out_specs=(state_spec, P(), P()),
+                       check_vma=False)
 
-    @partial(jax.jit, donate_argnums=(0,))
-    def run(pr, inv_deg, base):
+    @partial(jax.jit, donate_argnums=(6,))
+    def run(send_ids, eui, ps, pe, pd, mask, pr, inv_deg, base):
         return fn(pr, inv_deg, base, mask, send_ids, eui, ps, pe, pd)
 
-    return run
+    return jax.tree_util.Partial(run, *_shard_streams(layout, mesh, axis))
 
 
 def sharded_chunk_stepper(layout: ShardedPNG, mesh: Mesh, axis: str, *,
@@ -444,7 +450,6 @@ def sharded_chunk_stepper(layout: ShardedPNG, mesh: Mesh, axis: str, *,
     """
     if dangling not in ("none", "redistribute"):
         raise ValueError(f"unknown dangling policy {dangling!r}")
-    send_ids, eui, ps, pe, pd, mask = _shard_streams(layout)
     vec = P(axis)
     state_spec = P(axis, None)
     rep = P()
@@ -487,20 +492,21 @@ def sharded_chunk_stepper(layout: ShardedPNG, mesh: Mesh, axis: str, *,
             cond, body, (jnp.int32(0), pr, active, took0, res0))
         return pr, active, took, res
 
-    fn = shard_map(local_step, mesh=mesh,
-                   in_specs=(state_spec, state_spec, rep, rep, rep,
-                             vec, vec, P(axis, None, None),
-                             P(axis, None), P(axis, None),
-                             P(axis, None), P(axis, None)),
-                   out_specs=(state_spec, rep, rep, rep),
-                   check_rep=False)
+    fn = jax.shard_map(local_step, mesh=mesh,
+                       in_specs=(state_spec, state_spec, rep, rep, rep,
+                                 vec, vec, P(axis, None, None),
+                                 P(axis, None), P(axis, None),
+                                 P(axis, None), P(axis, None)),
+                       out_specs=(state_spec, rep, rep, rep),
+                       check_vma=False)
 
-    @partial(jax.jit, donate_argnums=(0,))
-    def step(pr, base, active, tol_col, budget, inv_deg):
+    @partial(jax.jit, donate_argnums=(6,))
+    def step(send_ids, eui, ps, pe, pd, mask, pr, base, active, tol_col,
+             budget, inv_deg):
         return fn(pr, base, active, tol_col, budget, inv_deg, mask,
                   send_ids, eui, ps, pe, pd)
 
-    return step
+    return jax.tree_util.Partial(step, *_shard_streams(layout, mesh, axis))
 
 
 def _padded_inv_degree(g: Graph, layout: ShardedPNG) -> np.ndarray:

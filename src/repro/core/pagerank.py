@@ -54,10 +54,11 @@ def fused_power_iteration(engine: SpMVEngine, *, damping: float = 0.85,
     """Build (and cache on the engine) the jitted fused iteration loop.
 
     Returns a callable ``run(pr0, inv_deg, base) -> (pr, it, residuals)``
-    where ``pr0`` is donated, ``base`` is the already-(1-damping)-scaled
-    teleport vector (same shape as ``pr0``; a uniform vector for plain
-    PageRank, per-column seed distributions for personalized queries),
-    and ``residuals`` is a (num_iterations,) device array with -1.0 in
+    (a ``Partial`` that passes the plan's streams to the jitted loop as
+    arguments; see ``compile_bound``) where ``pr0`` is donated,
+    ``base`` is the already-(1-damping)-scaled teleport vector (same
+    shape as ``pr0``; a uniform vector for plain PageRank, per-column
+    seed distributions for personalized queries), and ``residuals`` is a (num_iterations,) device array with -1.0 in
     slots where convergence was not checked.
 
     With ``multi=True`` the state is (n, d) — d independent rank vectors
@@ -84,11 +85,8 @@ def fused_power_iteration(engine: SpMVEngine, *, damping: float = 0.85,
     if cached is not None:
         return cached
 
-    spmv = engine.spmv_fn()
-    n = engine.num_nodes
-
-    @partial(jax.jit, donate_argnums=(0,))
-    def run(pr, inv_deg, base):
+    @partial(jax.jit, donate_argnums=(1,))
+    def run(spmv, pr, inv_deg, base):
         if multi:
             inv_deg = inv_deg[:, None]
         # loop-invariant sink terms — XLA hoists both out of the body
@@ -122,6 +120,7 @@ def fused_power_iteration(engine: SpMVEngine, *, damping: float = 0.85,
             cond, body, (jnp.int32(0), pr, residuals0, jnp.bool_(False)))
         return pr, it, residuals
 
+    run = jax.tree_util.Partial(run, engine.spmv_fn())
     engine._fused_cache[key] = run
     return run
 
@@ -164,10 +163,8 @@ def masked_chunk_stepper(engine: SpMVEngine, *, damping: float = 0.85,
     if cached is not None:
         return cached
 
-    spmv = engine.spmv_fn()
-
-    @partial(jax.jit, donate_argnums=(0,))
-    def step(pr, base, active, tol_col, budget, inv_deg):
+    @partial(jax.jit, donate_argnums=(1,))
+    def step(spmv, pr, base, active, tol_col, budget, inv_deg):
         inv_col = inv_deg[:, None]
         dang_col = (inv_col == 0).astype(pr.dtype)
         redist = base * (damping / (1.0 - damping))
@@ -202,8 +199,27 @@ def masked_chunk_stepper(engine: SpMVEngine, *, damping: float = 0.85,
             cond, body, (jnp.int32(0), pr, active, took0, res0))
         return pr, active, took, res
 
+    step = jax.tree_util.Partial(step, engine.spmv_fn())
     engine._fused_cache[key] = step
     return step
+
+
+def compile_bound(loop, *specs, donate_argnums=(0,), on_trace=None):
+    """AOT-compile a loop built above (a ``Partial`` of a jitted
+    function over the plan's streams) for ``specs``, with the streams
+    as arguments and ``on_trace()`` run at each trace.  Returns the
+    executable with the streams bound: ``(*args) -> outputs``."""
+    jitted, bound = loop.func, loop.args
+
+    def traced(*args):
+        if on_trace is not None:
+            on_trace()
+        return jitted.__wrapped__(*args)
+
+    donate = tuple(len(bound) + i for i in donate_argnums)
+    compiled = (jax.jit(traced, donate_argnums=donate)
+                .lower(*bound, *specs).compile())
+    return partial(compiled, *bound)
 
 
 def _run_fused(g: Graph, eng: SpMVEngine, *, num_iterations: int,
@@ -258,7 +274,7 @@ def _run_python(g: Graph, eng: SpMVEngine, *, num_iterations: int,
 
 
 def pagerank(g: Graph, *, method: str = "pcpm", num_iterations: int = 20,
-             damping: float = 0.85, part_size: int = 65536,
+             damping: float = 0.85, part_size: int | None = None,
              tol: float = 0.0, engine: SpMVEngine | None = None,
              driver: str = "fused", check_every: int = 1,
              dangling: str = "none") -> PageRankResult:

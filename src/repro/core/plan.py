@@ -44,13 +44,16 @@ from .png import BlockedPNG, GatherSchedule, PNGLayout, build_png
 # Config
 # ---------------------------------------------------------------------------
 DEFAULT_GATHER_BLOCK = 256
+DEFAULT_PART_SIZE = 65536     # 256 KB of 4-byte values (paper §VI-C)
 
 
 @dataclasses.dataclass(frozen=True)
 class PlanConfig:
     """Host-preprocessing knobs.  Hashable — the cache key half."""
     method: str = "pcpm"
-    part_size: int = 65536
+    # None: the backend derives it (DEFAULT_PART_SIZE, or for
+    # pcpm_pallas the largest size whose kernel fits VMEM)
+    part_size: Optional[int] = None
     num_shards: Optional[int] = None   # sharded backends; None = all devices
     shard_axis: str = "shards"
     gather_block: int = DEFAULT_GATHER_BLOCK
@@ -438,8 +441,10 @@ def validate_plan(g: Graph, plan: GraphPlan) -> GraphPlan:
 
 def shared_png(g: Graph, part_size: int) -> PNGLayout:
     """The PNG layout for ``(graph, part_size)`` — method-independent,
-    so ``pcpm`` and ``pcpm_pallas`` plans share ONE build (the old
-    ``SpMVEngine`` built it once per constructor per method)."""
+    so ``pcpm`` and ``pcpm_pallas`` plans at the same part size share
+    ONE build.  Their default part sizes differ (``pcpm_pallas`` derives
+    its own from VMEM), so they share only when both are given the same
+    explicit size that the kernel can fit."""
     key = (graph_fingerprint(g), part_size)
     png = _PNG_CACHE.get(key)
     if png is not None:

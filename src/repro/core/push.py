@@ -161,14 +161,16 @@ def residual_push_loop(plan: GraphPlan, *, damping: float = 0.85,
                               *streams, num_nodes=n, block=blk,
                               damping=damping, dangling=dangling)
     else:
-        spmv = spmv_fn(plan)
         n = plan.num_nodes
 
-        @partial(jax.jit, donate_argnums=(0, 1))
-        def run(pr, r, inv_deg, tol, max_push):
+        # the plan's streams enter as an argument (core/backends.py)
+        @partial(jax.jit, donate_argnums=(1, 2))
+        def loop(spmv, pr, r, inv_deg, tol, max_push):
             return _push_while(pr, r, inv_deg, tol, max_push, spmv,
                                num_nodes=n, damping=damping,
                                dangling=dangling)
+
+        run = jax.tree_util.Partial(loop, spmv_fn(plan))
 
     cache[key] = run
     return run
@@ -190,16 +192,16 @@ def seed_query_state(plan: GraphPlan, *, damping: float = 0.85,
     if cached is not None:
         return cached
 
-    spmv = spmv_fn(plan)
     n = plan.num_nodes
 
     @jax.jit
-    def init(seed, inv_deg):
+    def init(spmv, seed, inv_deg):
         x1 = (1.0 - damping) * seed + damping * spmv(seed * inv_deg)
         if dangling == "redistribute":
             dang = (inv_deg == 0).astype(seed.dtype)
             x1 = x1 + (seed * dang).sum() * (damping / n)
         return seed, x1 - seed
 
+    init = jax.tree_util.Partial(init, spmv_fn(plan))
     cache[key] = init
     return init

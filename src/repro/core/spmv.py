@@ -220,7 +220,7 @@ class SpMVEngine:
     """
 
     def __init__(self, g: Graph, *, method: str = "pcpm",
-                 part_size: int = 65536, two_phase: bool = False,
+                 part_size: int | None = None, two_phase: bool = False,
                  num_shards: int | None = None, plan=None):
         from . import backends
         from .plan import PlanConfig, build_plan, validate_plan

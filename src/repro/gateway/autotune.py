@@ -57,7 +57,8 @@ def autotune_slots(engine, *, chunk: int,
     if not engine.backend.multi_vector:
         return AutotuneReport(target_chunk_s, chunk, {}, default)
     n = engine.num_nodes
-    fn = jax.jit(engine.spmv_fn())
+    fn = jax.tree_util.Partial(jax.jit(lambda spmv, x: spmv(x)),
+                               engine.spmv_fn())
     rng = np.random.default_rng(0)
     probes: dict[int, float] = {}
     for b in sorted(set(int(b) for b in candidates)):

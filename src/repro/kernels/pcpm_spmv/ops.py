@@ -11,8 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...core.png import BlockedPNG
-from .kernel import pcpm_gather_pallas
-from .ref import pcpm_gather_ref
+from .kernel import EDGE_BLOCK, EDGE_ROWS, LANES, pcpm_gather_pallas
 
 
 def _round_up(x: int, mult: int) -> int:
@@ -35,11 +34,14 @@ class PackedPNG:
 
 
 def pack_blocked(blocked: BlockedPNG, num_nodes: int, *,
-                 edge_block: int = 512, lane: int = 128) -> PackedPNG:
+                 edge_block: int = EDGE_BLOCK,
+                 lane: int = LANES) -> PackedPNG:
+    """Pad the blocked PNG to the kernel's tiles: U to a lane multiple,
+    the edge streams to whole (EDGE_ROWS, edge_block) tiles."""
     k, max_u = blocked.update_src.shape
     _, max_e = blocked.edge_update_local.shape
     u_pad = _round_up(max(max_u, lane), lane)
-    e_pad = _round_up(max(max_e, edge_block), edge_block)
+    e_pad = _round_up(max(max_e, 1), EDGE_ROWS * edge_block)
 
     upd = np.zeros((k, u_pad), dtype=np.int32)
     valid = np.zeros((k, u_pad), dtype=bool)
@@ -60,34 +62,32 @@ def pack_blocked(blocked: BlockedPNG, num_nodes: int, *,
         jnp.asarray(ed.reshape(k, n_eb, edge_block)))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("interpret", "use_kernel", "u_tile"))
+@functools.partial(jax.jit, static_argnames=("interpret", "u_tile"))
 def pcpm_spmv_pallas(packed: PackedPNG, x: jnp.ndarray, *,
                      interpret: bool | None = None,
-                     use_kernel: bool = True,
                      u_tile: int | None = None) -> jnp.ndarray:
     """y = A^T x. x: (n,) or (n, d) with any d >= 1 (multi-vector /
-    personalized-query batches; d is padded to the 128-lane boundary).
+    personalized-query batches).  Columns go through the kernel
+    ``LANES`` at a time, padded to the lane width, so its VMEM working
+    set never depends on d.
 
-    ``interpret=None`` compiles the kernel on TPU and falls back to the
-    Pallas interpreter elsewhere (kernel.default_interpret).
-    """
+    ``interpret=None`` compiles the kernel on TPU and interprets it on
+    the CPU (kernel.default_interpret)."""
     squeeze = x.ndim == 1
     if squeeze:
         x = x[:, None]
     n, d = x.shape
-    d_pad = _round_up(max(d, 128), 128)
+    d_pad = _round_up(d, LANES)
     if d_pad != d:
         x = jnp.pad(x, ((0, 0), (0, d_pad - d)))
     # scatter phase: compressed bins (k, U, d) — one value per
     # (src, dst-partition) pair, the paper's update_bins.
     bins = x[packed.update_src] * packed.update_valid[..., None]
-    fn = pcpm_gather_pallas if use_kernel else (
-        lambda b, eu, ed, part_size, interpret=None, u_tile=None:
-        pcpm_gather_ref(b, eu, ed, part_size=part_size))
-    out = fn(bins, packed.edge_upd, packed.edge_dst,
-             part_size=packed.part_size, interpret=interpret,
-             u_tile=u_tile)
+    out = jnp.concatenate([
+        pcpm_gather_pallas(bins[..., c:c + LANES], packed.edge_upd,
+                           packed.edge_dst, part_size=packed.part_size,
+                           interpret=interpret, u_tile=u_tile)
+        for c in range(0, d_pad, LANES)], axis=-1)
     y = out.reshape(-1, d_pad)[:n, :d]
     return y[:, 0] if squeeze else y
 

@@ -28,7 +28,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..configs.base import GNNConfig
 from ..core.distributed import ShardedPNG, build_sharded_png
@@ -169,9 +168,9 @@ def graphcast_dist_forward(params: dict, cfg: GNNConfig, g: DistGraph,
     vec = P(ax)
     mat1 = P(ax, None)
     mat2 = P(ax, None, None)
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(mat1, mat1, vec, mat2, mat1, mat1, P()),
-                   out_specs=mat1)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(mat1, mat1, vec, mat2, mat1, mat1, P()),
+                       out_specs=mat1, check_vma=False)
     return fn(g.node_feat, g.positions, g.labels, g.send_ids,
               g.edge_upd, g.edge_dst, params)
 

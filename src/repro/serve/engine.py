@@ -27,7 +27,8 @@ import jax.numpy as jnp
 
 from ..configs.base import LMConfig
 from ..core.backends import resolve_engine, reorder_device
-from ..core.pagerank import _inv_degree, fused_power_iteration
+from ..core.pagerank import (_inv_degree, compile_bound,
+                             fused_power_iteration)
 from ..core.plan import internal_graph, reorder_inverse
 from ..core.spmv import SpMVEngine
 from ..graphs.formats import Graph
@@ -101,7 +102,7 @@ class PageRankServer:
     """
 
     def __init__(self, g: Graph, *, method: str = "pcpm_pallas",
-                 part_size: int = 65536, batch: int = 1,
+                 part_size: int | None = None, batch: int = 1,
                  damping: float = 0.85, num_iterations: int = 20,
                  tol: float = 0.0, check_every: int = 1,
                  dangling: str = "none", sharded: bool = False,
@@ -157,12 +158,11 @@ class PageRankServer:
             spec = jax.ShapeDtypeStruct(shape, jnp.float32)
             inv_spec = jax.ShapeDtypeStruct((self.n,), jnp.float32)
 
-        def counted(pr, inv_deg, base):
+        def counted():
             self.trace_count += 1           # increments only at trace time
-            return run.__wrapped__(pr, inv_deg, base)
 
-        self._compiled = (jax.jit(counted, donate_argnums=(0,))
-                          .lower(spec, inv_spec, spec).compile())
+        self._compiled = compile_bound(run, spec, inv_spec, spec,
+                                       on_trace=counted)
 
     def _upload(self, host: np.ndarray):
         if self.sharded:

@@ -62,7 +62,8 @@ import jax.numpy as jnp
 from ..core.backends import resolve_engine
 from ..core.plan import (install_plan, internal_graph, plan_nbytes,
                          reorder_inverse)
-from ..core.pagerank import _inv_degree, masked_chunk_stepper
+from ..core.pagerank import (_inv_degree, compile_bound,
+                             masked_chunk_stepper)
 from ..core.spmv import SpMVEngine
 from ..graphs.formats import Graph, validate_graph
 from ..graphs import io as graph_io
@@ -160,7 +161,7 @@ class SlotScheduler:
     """
 
     def __init__(self, g: Graph, *, slots: int = 4,
-                 method: str = "pcpm", part_size: int = 65536,
+                 method: str = "pcpm", part_size: int | None = None,
                  damping: float = 0.85, chunk: int = 8,
                  dangling: str = "none", sharded: bool = False,
                  num_shards: int | None = None,
@@ -370,16 +371,14 @@ class SlotScheduler:
                                         dangling=self.dangling)
             inv_deg = _inv_degree(gi)
 
-        def counted_step(pr, base, active, tol_col, budget, inv_deg):
+        def counted_step():
             self.trace_count += 1     # increments only at trace time
-            return step.__wrapped__(pr, base, active, tol_col, budget,
-                                    inv_deg)
 
         state_spec, act_spec, tol_spec, bud_spec, inv_spec = self._specs
         t0 = time.perf_counter()
-        step_c = (jax.jit(counted_step, donate_argnums=(0,))
-                  .lower(state_spec, state_spec, act_spec,
-                         tol_spec, bud_spec, inv_spec).compile())
+        step_c = compile_bound(step, state_spec, state_spec, act_spec,
+                               tol_spec, bud_spec, inv_spec,
+                               on_trace=counted_step)
         if self.obs is not None:
             # trace_count/rebind_count were only attributes until now;
             # this makes every XLA stepper compile a recorded event

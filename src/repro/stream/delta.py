@@ -52,10 +52,10 @@ def _as_edges(edges) -> tuple[np.ndarray, np.ndarray]:
 class GraphDelta:
     """One batch of edge changes: ``add_*`` are inserted, ``rem_*``
     removed (one multi-edge copy per entry)."""
-    add_src: np.ndarray = _EMPTY
-    add_dst: np.ndarray = _EMPTY
-    rem_src: np.ndarray = _EMPTY
-    rem_dst: np.ndarray = _EMPTY
+    add_src: np.ndarray = dataclasses.field(default_factory=_EMPTY.copy)
+    add_dst: np.ndarray = dataclasses.field(default_factory=_EMPTY.copy)
+    rem_src: np.ndarray = dataclasses.field(default_factory=_EMPTY.copy)
+    rem_dst: np.ndarray = dataclasses.field(default_factory=_EMPTY.copy)
 
     # ------------------------------------------------------ constructors
     @staticmethod

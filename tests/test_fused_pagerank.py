@@ -214,7 +214,8 @@ class TestDeviceResidency:
         n = g.num_nodes
         pr0 = jnp.full((n,), 1.0 / n, dtype=jnp.float32)
         base = jnp.full((n,), 0.15 / n, dtype=jnp.float32)
-        jaxpr = jax.make_jaxpr(run.__wrapped__)(pr0, _inv_degree(g), base)
+        jaxpr = jax.make_jaxpr(run.func.__wrapped__)(
+            *run.args, pr0, _inv_degree(g), base)
         prims = [str(e.primitive) for e in jaxpr.jaxpr.eqns]
         assert prims.count("while") == 1
         assert not any("callback" in p or "infeed" in p or "outfeed" in p

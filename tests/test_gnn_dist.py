@@ -22,7 +22,9 @@ SCRIPT = textwrap.dedent("""
                                        dist_graph_shardings)
     from repro.optim import AdamW
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    # Auto axes: the forward and the train step run under `with mesh`
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = get("graphcast").scaled()
     g = generators.rmat(9, 8, seed=5)       # 512 nodes, 4096 edges
     n, m, df, n_out = g.num_nodes, g.num_edges, 12, 8

@@ -5,8 +5,9 @@ import jax.numpy as jnp
 
 from repro.graphs import generators
 from repro.core import Partitioning, build_png, block_png
-from repro.kernels.pcpm_spmv import (pack_blocked, pcpm_spmv_pallas,
-                                     pcpm_gather_pallas, pcpm_gather_ref)
+from repro.kernels.pcpm_spmv import (kernel, pack_blocked,
+                                     pcpm_spmv_pallas, pcpm_gather_pallas,
+                                     pcpm_gather_ref)
 from repro.kernels.embedding_bag import (embedding_bag,
                                          embedding_bag_pallas,
                                          embedding_bag_ref)
@@ -39,7 +40,8 @@ class TestPCPMKernel:
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_kernel_vs_ref_direct(self, dtype):
-        k, U, d, P, Eb, neb = 4, 128, 128, 64, 128, 3
+        # two grid steps of edge rows, as pack_blocked pads them
+        k, U, d, P, Eb, neb = 4, 128, 128, 64, 128, 2 * kernel.EDGE_ROWS
         bins = jnp.asarray(RNG.random((k, U, d)), dtype=dtype)
         eu = jnp.asarray(RNG.integers(0, U + 1, (k, neb, Eb)), dtype=jnp.int32)
         ed = jnp.asarray(RNG.integers(0, P + 1, (k, neb, Eb)), dtype=jnp.int32)
@@ -55,10 +57,41 @@ class TestPCPMKernel:
         # a partition with zero edges must produce zeros
         k, U, d, P, Eb = 2, 128, 128, 8, 128
         bins = jnp.asarray(RNG.random((k, U, d)).astype(np.float32))
-        eu = jnp.full((k, 1, Eb), U, dtype=jnp.int32)   # all padding
-        ed = jnp.full((k, 1, Eb), P, dtype=jnp.int32)
+        shape = (k, kernel.EDGE_ROWS, Eb)                # all padding
+        eu = jnp.full(shape, U, dtype=jnp.int32)
+        ed = jnp.full(shape, P, dtype=jnp.int32)
         out = pcpm_gather_pallas(bins, eu, ed, part_size=P, interpret=True)
         assert np.allclose(np.asarray(out), 0.0)
+
+    @pytest.mark.parametrize("backend,interpret,want", [
+        ("cpu", None, True), ("cpu", True, True),
+        ("tpu", None, False), ("tpu", False, False),
+        ("tpu", True, ValueError), ("gpu", None, RuntimeError),
+    ])
+    def test_interpreter_only_on_cpu(self, monkeypatch, backend, interpret,
+                                     want):
+        """A run that lost its chip fails; it never lands in the
+        interpreter."""
+        import jax
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        if isinstance(want, bool):
+            assert kernel.default_interpret(interpret) is want
+        else:
+            with pytest.raises(want):
+                kernel.default_interpret(interpret)
+
+    def test_part_size_derived_from_vmem(self):
+        import repro
+        top = kernel.max_part_size()
+        assert kernel.vmem_bytes(top) <= kernel.VMEM_LIMIT
+        assert kernel.vmem_bytes(2 * top) > kernel.VMEM_LIMIT
+        small = generators.rmat(6, 4, seed=0)       # fits one partition
+        sess = repro.open(small, method="pcpm_pallas")
+        assert sess.plan.part_size == small.num_nodes
+        big = generators.rmat(top.bit_length(), 1, seed=0)
+        assert repro.open(big, method="pcpm_pallas").plan.part_size == top
+        with pytest.raises(ValueError, match="VMEM"):
+            repro.open(big, method="pcpm_pallas", part_size=2 * top)
 
 
 # ---------------------------------------------------------- embedding_bag
