@@ -1,33 +1,24 @@
-"""Measured-vs-model communication accounting rows (DESIGN.md §14).
+"""Plan-counted vs model communication rows (DESIGN.md §14).
 
 Where fig8_comm.py reports the paper's ANALYTIC byte model (eqs. 3-5)
-and the compiled HLO's "bytes accessed", this job reports the third
-surface the obs layer adds: bytes counted off the plan's REAL array
-geometry — the padded streams the backends actually bind — accumulated
-per executed pass by ``obs.comm.CommAccountant``.
+and the compiled HLO's "bytes accessed", this job reports the bytes
+counted off the plan's REAL array geometry — the padded streams the
+backends actually bind (``obs.comm.measure_plan``).
 
-Three rows per (dataset, method):
+Two rows per (dataset, method):
 
   comm/<ds>/<m>/measured — DRAM-model bytes/iteration off the plan
                            geometry (padding included, on-chip bins
                            traffic reported in ``derived`` separately)
   comm/<ds>/<m>/model    — the paper's eq. 3-5 prediction at the
                            plan's measured r, plus measured/model ratio
-  comm/<ds>/<m>/live     — a real observed solve (Session with
-                           ``observe=True``): executed passes counted
-                           by the scheduler hook, accumulated bytes,
-                           and the accountant's own ratio_vs_model —
-                           proving the serving-path counters and the
-                           static measurement agree
 
-The pcpm measured/model ratio is the PR's acceptance bound (within 2x
-at scale 16); the gap's composition — schedule padding, the bins write
-+ read round trip eq. 5 folds into 1/r terms — is quantified in
-DESIGN.md §14.
+The pcpm measured/model ratio stays within 2x at scale 16; the gap's
+composition — schedule padding, the bins write + read round trip
+eq. 5 folds into 1/r terms — is quantified in DESIGN.md §14.
 """
 from __future__ import annotations
 
-import repro
 from repro.core.plan import PlanConfig, build_plan
 from repro.obs import vs_model
 
@@ -36,8 +27,7 @@ from .common import Csv, Dataset
 METHODS = ("pcpm", "pdpr", "bvgas")
 
 
-def run(datasets: list[Dataset], *, part_size: int = 65536,
-        iters: int = 10) -> Csv:
+def run(datasets: list[Dataset], *, part_size: int = 65536) -> Csv:
     csv = Csv()
     for ds in datasets:
         for method in METHODS:
@@ -53,19 +43,6 @@ def run(datasets: list[Dataset], *, part_size: int = 65536,
             if "model_bytes_per_iter_best" in cmp_:
                 derived += (f",best={cmp_['model_bytes_per_iter_best']:.0f}")
             csv.add(f"comm/{ds.name}/{method}/model", 0.0, derived)
-
-            # live: the scheduler/solver hook path, not a recount
-            sess = repro.open(ds.graph, repro.EngineConfig(
-                method=method, part_size=part_size,
-                num_iterations=iters, observe=True))
-            sess.pagerank()
-            summ = sess.obs.comm.summary().get(method)
-            if summ:
-                csv.add(f"comm/{ds.name}/{method}/live", 0.0,
-                        f"passes={summ['passes']},"
-                        f"bytes={summ['dram_bytes']:.0f},"
-                        f"ratio={summ.get('ratio_vs_model', 0):.2f}")
-            sess.obs.close()
     return csv
 
 
@@ -74,10 +51,10 @@ def summarize(rows) -> dict:
     method, measured vs model bytes/iteration and their ratio."""
     summ: dict = {}
 
-    def _field(derived, key, cast=float):
+    def _field(derived, key):
         for part in derived.split(","):
             if part.startswith(key + "="):
-                return cast(part.split("=", 1)[1])
+                return float(part.split("=", 1)[1])
         return None
 
     for n, _us, derived in rows:
@@ -91,7 +68,4 @@ def summarize(rows) -> dict:
             e["model_bytes_per_iter"] = _field(derived, "B/iter")
             e["ratio"] = _field(derived, "ratio")
             e["r"] = _field(derived, "r")
-        elif kind == "live":
-            e["live_passes"] = _field(derived, "passes", int)
-            e["live_ratio"] = _field(derived, "ratio")
     return summ

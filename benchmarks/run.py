@@ -99,7 +99,7 @@ def main(argv=None) -> int:
             datasets[:2], part_size=args.part_size,
             orderings=(["none", args.reorder] if args.reorder
                        else None)),
-        # measured-vs-model comm accounting (DESIGN.md §14)
+        # plan-counted vs model comm bytes (DESIGN.md §14)
         "comm": lambda: comm_live.run(datasets[:2],
                                       part_size=args.part_size),
     }

@@ -325,7 +325,6 @@ def observe(args):
     with open(stats_path, "w") as f:
         json.dump(stats, f, indent=1, default=str)
 
-    comm = stats["obs"]["comm"].get("pcpm", {})
     fr = stats["obs"]["flight_recorder"]
     print(f"storm: {len(results)} queries in {dt * 1e3:.0f}ms "
           f"({len(results) / dt:.0f} qps), solve {res.iterations} iters")
@@ -334,14 +333,10 @@ def observe(args):
           f"(capacity {fr['capacity']})")
     print(f"span trees: {len(roots)} roots, {len(terms)} terminals — "
           f"well-nested, exactly one terminal each")
-    print(f"comm accountant: {comm.get('passes', 0)} passes, "
-          f"{comm.get('dram_bytes', 0):.3g} B measured, "
-          f"ratio_vs_model={comm.get('ratio_vs_model', 0):.2f}")
     print(f"artifacts: {trace_path} ({fr['held']} records), "
           f"{prom_path} ({len(prom.splitlines())} lines), {stats_path}")
     print("observability demo OK: traced solve + gateway storm, "
-          "complete span trees, measured comm within model's regime, "
-          "zero retraces")
+          "complete span trees, zero retraces")
 
 
 def main():

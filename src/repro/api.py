@@ -41,6 +41,7 @@ from .core.plan import (DEFAULT_GATHER_BLOCK, GraphPlan, PlanConfig,
                         build_plan)
 from .core.spmv import SpMVEngine
 from .graphs.formats import Graph
+from .obs.trace import phase
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,8 +75,8 @@ class EngineConfig:
     chunk: int = 8
     # observability (DESIGN.md §14): OFF by default — when True the
     # session owns an ``obs.Observability`` bundle (span tracer +
-    # flight recorder + metrics registry + comm accountant) and every
-    # workload it fans out reports through it
+    # flight recorder + metrics registry) and every workload it fans
+    # out reports through it
     observe: bool = False
 
     def plan_config(self) -> PlanConfig:
@@ -137,11 +138,11 @@ class Session:
     def observe(self, *, capacity: int = 8192, dump_dir=None):
         """Attach (or return) this session's ``Observability`` bundle
         (DESIGN.md §14).  Idempotent: the first call creates the
-        bundle — span tracer over a bounded flight recorder, typed
-        metrics registry, and the measured-comm accountant — and every
-        later call returns the same one.  Handles created AFTER the
-        bundle exists (``serve()``/``gateway()``) report through it;
-        ``pagerank``/``apply_delta`` on this session do too."""
+        bundle — span tracer over a bounded flight recorder and typed
+        metrics registry — and every later call returns the same one.
+        Handles created AFTER the bundle exists (``serve()``/
+        ``gateway()``) report through it; ``pagerank``/``apply_delta``
+        on this session do too."""
         if self._obs is None:
             from .obs import Observability
             self._obs = Observability(capacity=capacity,
@@ -156,9 +157,9 @@ class Session:
 
     def stats(self) -> dict:
         """One dict joining every cache/observability surface the
-        session can see: process-level plan-cache counters, and — when
-        observing — the metrics registry, comm summary and flight-
-        recorder occupancy."""
+        session can see: process-level plan-cache counters (with the
+        cumulative seconds of each build phase), and — when observing
+        — the metrics registry and flight-recorder occupancy."""
         from .core.plan import plan_cache_stats
         out = {"plan_cache": dataclasses.asdict(plan_cache_stats()),
                "method": self.config.method,
@@ -237,11 +238,10 @@ class Session:
         warm_hit = (warm and self._solved_ranks is not None
                     and self._solved_key == key
                     and 0.0 < tol and self._solved_res <= tol)
-        sp = (self._obs.tracer.start(
-                  "solve", trace="plan", method=self.config.method,
-                  warm=bool(warm_hit), n=self.plan.num_nodes)
-              if self._obs is not None else None)
-        try:
+        tracer = self._obs.tracer if self._obs is not None else None
+        with phase("repro.solve", tracer, trace="plan",
+                   method=self.config.method, warm=bool(warm_hit),
+                   n=self.plan.num_nodes) as sp:
             if warm_hit:
                 from .stream.delta import GraphDelta
                 from .stream.incremental import update_ranks
@@ -252,18 +252,9 @@ class Session:
                     dangling=kw["dangling"], tol=tol, max_push=budget)
             else:
                 res = pagerank(self.graph, engine=self.engine, **kw)
-        except Exception as e:
             if sp is not None:
-                sp.end(status="error", error=repr(e))
-            raise
-        if self._obs is not None:
-            if not warm_hit:
-                # measured comm: one full gather/scatter pass per
-                # executed power iteration (warm pushes are sparse and
-                # don't stream the whole edge structure)
-                self._obs.comm.record_solve(self.plan, res.iterations)
-            sp.end(iterations=res.iterations,
-                   residual=float((res.residuals or [np.inf])[-1]))
+                sp.annotate(iterations=res.iterations, residual=float(
+                    (res.residuals or [np.inf])[-1]))
         achieved = (res.residuals or [np.inf])[-1]
         self._solved_graph = self.graph
         self._solved_ranks = res.ranks

@@ -33,7 +33,8 @@ import jax.numpy as jnp
 
 from ..graphs.formats import Graph
 from .partition import Partitioning
-from .plan import DEFAULT_PART_SIZE, GraphPlan, PlanConfig, shared_png
+from .plan import (DEFAULT_PART_SIZE, GraphPlan, PlanConfig, build_phase,
+                   shared_png)
 from .png import (GatherSchedule, block_png, build_gather_schedule,
                   flat_gather_schedule)
 
@@ -284,20 +285,26 @@ def pdpr_schedule(csc_src: np.ndarray, csc_dst: np.ndarray, *,
 def _build_pdpr(g: Graph, cfg: PlanConfig) -> GraphPlan:
     order = np.lexsort((g.src, g.dst))
     src, dst = g.src[order], g.dst[order]
-    return GraphPlan(csc_src=src, csc_dst=dst,
-                     schedule=pdpr_schedule(src, dst,
-                                            num_nodes=g.num_nodes,
-                                            block=cfg.gather_block),
+    with build_phase("schedule"):
+        sched = pdpr_schedule(src, dst, num_nodes=g.num_nodes,
+                              block=cfg.gather_block)
+    return GraphPlan(csc_src=src, csc_dst=dst, schedule=sched,
                      **_plan_fields(g, cfg))
+
+
+def _upload(*arrays):
+    """The plan's host streams on the device, waited for (so the
+    ``upload`` phase holds the transfer, not just its enqueue)."""
+    with build_phase("upload"):
+        return jax.block_until_ready(tuple(map(jnp.asarray, arrays)))
 
 
 def _sched_device(plan: GraphPlan):
     dev = plan._device.get("sched")
     if dev is None:
         s = plan.schedule
-        dev = (jnp.asarray(s.edge_update_idx_padded),
-               jnp.asarray(s.piece_start), jnp.asarray(s.piece_end),
-               jnp.asarray(s.piece_dst))
+        dev = _upload(s.edge_update_idx_padded, s.piece_start,
+                      s.piece_end, s.piece_dst)
         plan._device["sched"] = dev
     return dev
 
@@ -340,16 +347,17 @@ def _build_bvgas(g: Graph, cfg: PlanConfig) -> GraphPlan:
     dstp = g.dst.astype(np.int64) // cfg.part_size
     order = np.lexsort((g.dst, g.src, dstp))
     dst = g.dst[order]
-    return GraphPlan(bv_src=g.src[order], bv_dst=dst,
-                     schedule=bvgas_schedule(dst, num_nodes=g.num_nodes,
-                                             block=cfg.gather_block),
+    with build_phase("schedule"):
+        sched = bvgas_schedule(dst, num_nodes=g.num_nodes,
+                               block=cfg.gather_block)
+    return GraphPlan(bv_src=g.src[order], bv_dst=dst, schedule=sched,
                      **_plan_fields(g, cfg))
 
 
 def _bvgas_device(plan: GraphPlan):
     dev = plan._device.get("bvgas")
     if dev is None:
-        dev = jnp.asarray(plan.bv_src)
+        (dev,) = _upload(plan.bv_src)
         plan._device["bvgas"] = dev
     return dev
 
@@ -384,7 +392,8 @@ def _phases_bvgas(plan: GraphPlan):
 # ---------------------------------------------------------------------------
 def _build_pcpm(g: Graph, cfg: PlanConfig) -> GraphPlan:
     png = shared_png(g, cfg.part_size)
-    sched = build_gather_schedule(png, block=cfg.gather_block)
+    with build_phase("schedule"):
+        sched = build_gather_schedule(png, block=cfg.gather_block)
     return GraphPlan(png=png, schedule=sched, **_plan_fields(g, cfg))
 
 
@@ -392,10 +401,8 @@ def _pcpm_device(plan: GraphPlan):
     dev = plan._device.get("pcpm")
     if dev is None:
         s = plan.schedule
-        dev = (jnp.asarray(plan.png.update_src),
-               jnp.asarray(s.edge_update_idx_padded),
-               jnp.asarray(s.piece_start), jnp.asarray(s.piece_end),
-               jnp.asarray(s.piece_dst))
+        dev = _upload(plan.png.update_src, s.edge_update_idx_padded,
+                      s.piece_start, s.piece_end, s.piece_dst)
         plan._device["pcpm"] = dev
     return dev
 

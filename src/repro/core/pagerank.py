@@ -27,7 +27,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..graphs.formats import Graph
+from ..obs.trace import phase
 from .spmv import SpMVEngine
+
+# named scope of the loops' own work around the SpMV pass (inverse-
+# degree scaling, damping, dangling mass, residual, exit test); the
+# pass itself is under core/spmv.py's ``pcpm.*`` scopes
+APPLY_SCOPE = "pagerank.apply"
 
 
 @dataclasses.dataclass
@@ -87,34 +93,39 @@ def fused_power_iteration(engine: SpMVEngine, *, damping: float = 0.85,
 
     @partial(jax.jit, donate_argnums=(1,))
     def run(spmv, pr, inv_deg, base):
-        if multi:
-            inv_deg = inv_deg[:, None]
-        # loop-invariant sink terms — XLA hoists both out of the body
-        dang = (inv_deg == 0).astype(pr.dtype)
-        redist = base * (damping / (1.0 - damping))
-        residuals0 = jnp.full((max(num_iterations, 1),), -1.0,
-                              dtype=jnp.float32)
+        with jax.named_scope(APPLY_SCOPE):
+            if multi:
+                inv_deg = inv_deg[:, None]
+            # loop-invariant sink terms — XLA hoists both out of the body
+            dang = (inv_deg == 0).astype(pr.dtype)
+            redist = base * (damping / (1.0 - damping))
+            residuals0 = jnp.full((max(num_iterations, 1),), -1.0,
+                                  dtype=jnp.float32)
 
         def cond(state):
             it, _, _, done = state
-            return (it < num_iterations) & ~done
+            with jax.named_scope(APPLY_SCOPE):
+                return (it < num_iterations) & ~done
 
         def body(state):
             it, pr, residuals, done = state
-            spr = pr * inv_deg                  # scaled ranks (alg.1 l.3)
-            pr_next = base + damping * spmv(spr)
-            if dangling == "redistribute":
-                dmass = (pr * dang).sum(axis=0)
-                pr_next = pr_next + dmass * redist
-            check = (((it + 1) % check_every == 0)
-                     | (it + 1 >= num_iterations))
-            res = jnp.where(
-                check, jnp.abs(pr_next - pr).sum(axis=0).max()
-                if multi else jnp.abs(pr_next - pr).sum(), -1.0)
-            residuals = residuals.at[it].set(res)
-            if tol > 0:
-                done = done | (check & (res >= 0) & (res < tol))
-            return it + 1, pr_next, residuals, done
+            with jax.named_scope(APPLY_SCOPE):
+                spr = pr * inv_deg              # scaled ranks (alg.1 l.3)
+            y = spmv(spr)
+            with jax.named_scope(APPLY_SCOPE):
+                pr_next = base + damping * y
+                if dangling == "redistribute":
+                    dmass = (pr * dang).sum(axis=0)
+                    pr_next = pr_next + dmass * redist
+                check = (((it + 1) % check_every == 0)
+                         | (it + 1 >= num_iterations))
+                res = jnp.where(
+                    check, jnp.abs(pr_next - pr).sum(axis=0).max()
+                    if multi else jnp.abs(pr_next - pr).sum(), -1.0)
+                residuals = residuals.at[it].set(res)
+                if tol > 0:
+                    done = done | (check & (res >= 0) & (res < tol))
+                return it + 1, pr_next, residuals, done
 
         it, pr, residuals, _ = jax.lax.while_loop(
             cond, body, (jnp.int32(0), pr, residuals0, jnp.bool_(False)))
@@ -165,35 +176,41 @@ def masked_chunk_stepper(engine: SpMVEngine, *, damping: float = 0.85,
 
     @partial(jax.jit, donate_argnums=(1,))
     def step(spmv, pr, base, active, tol_col, budget, inv_deg):
-        inv_col = inv_deg[:, None]
-        dang_col = (inv_col == 0).astype(pr.dtype)
-        redist = base * (damping / (1.0 - damping))
-        took0 = jnp.zeros(pr.shape[1], dtype=jnp.int32)
-        res0 = jnp.full((pr.shape[1],), -1.0, dtype=jnp.float32)
+        with jax.named_scope(APPLY_SCOPE):
+            inv_col = inv_deg[:, None]
+            dang_col = (inv_col == 0).astype(pr.dtype)
+            redist = base * (damping / (1.0 - damping))
+            took0 = jnp.zeros(pr.shape[1], dtype=jnp.int32)
+            res0 = jnp.full((pr.shape[1],), -1.0, dtype=jnp.float32)
 
         def cond(state):
             i, _, act, _, _ = state
-            return (i < chunk) & act.any()
+            with jax.named_scope(APPLY_SCOPE):
+                return (i < chunk) & act.any()
 
         def body(state):
             i, pr, act, took, res = state
-            spr = pr * inv_col                  # scaled ranks (alg.1 l.3)
-            pr_next = base + damping * spmv(spr)
-            if dangling == "redistribute":
-                dmass = (pr * dang_col).sum(axis=0)       # (B,)
-                pr_next = pr_next + dmass[None, :] * redist
-            r = jnp.abs(pr_next - pr).sum(axis=0)         # (B,) per slot
-            pr = jnp.where(act[None, :], pr_next, pr)     # freeze others
-            res = jnp.where(act, r, res)
-            took = took + act.astype(jnp.int32)
-            # quarantine guardrail (DESIGN.md §10): a non-finite L1
-            # residual means the column is NaN/Inf-poisoned — freeze it
-            # immediately (NaN already compares False below, but +Inf
-            # would keep burning budget) so the host sees the non-
-            # finite residual and quarantines the slot.  Folded into
-            # the existing reduction: no extra device sync.
-            act = act & jnp.isfinite(r) & (r >= tol_col) & (took < budget)
-            return i + 1, pr, act, took, res
+            with jax.named_scope(APPLY_SCOPE):
+                spr = pr * inv_col              # scaled ranks (alg.1 l.3)
+            y = spmv(spr)
+            with jax.named_scope(APPLY_SCOPE):
+                pr_next = base + damping * y
+                if dangling == "redistribute":
+                    dmass = (pr * dang_col).sum(axis=0)       # (B,)
+                    pr_next = pr_next + dmass[None, :] * redist
+                r = jnp.abs(pr_next - pr).sum(axis=0)         # (B,) per slot
+                pr = jnp.where(act[None, :], pr_next, pr)     # freeze others
+                res = jnp.where(act, r, res)
+                took = took + act.astype(jnp.int32)
+                # quarantine guardrail (DESIGN.md §10): a non-finite L1
+                # residual means the column is NaN/Inf-poisoned — freeze
+                # it immediately (NaN already compares False below, but
+                # +Inf would keep burning budget) so the host sees the
+                # non-finite residual and quarantines the slot.  Folded
+                # into the existing reduction: no extra device sync.
+                act = (act & jnp.isfinite(r) & (r >= tol_col)
+                       & (took < budget))
+                return i + 1, pr, act, took, res
 
         _, pr, active, took, res = jax.lax.while_loop(
             cond, body, (jnp.int32(0), pr, active, took0, res0))
@@ -239,10 +256,16 @@ def _run_fused(g: Graph, eng: SpMVEngine, *, num_iterations: int,
                                 num_iterations=num_iterations, tol=tol,
                                 check_every=check_every,
                                 dangling=dangling)
-    pr0 = jnp.full((n,), 1.0 / n, dtype=jnp.float32)
-    base = jnp.full((n,), (1.0 - damping) / n, dtype=jnp.float32)
-    pr, it, res = run(pr0, _inv_degree(g), base)
-    res_host = np.asarray(res)[:int(it)]
+    with phase("repro.solve.inputs"):
+        pr0 = jnp.full((n,), 1.0 / n, dtype=jnp.float32)
+        base = jnp.full((n,), (1.0 - damping) / n, dtype=jnp.float32)
+        # waited for here, so that the device's wait for the upload
+        # lies inside this span and not in the next one
+        inputs = jax.block_until_ready((pr0, _inv_degree(g), base))
+    with phase("repro.solve.run"):
+        pr, it, res = run(*inputs)
+    with phase("repro.solve.readback"):
+        res_host = np.asarray(res)[:int(it)]
     return PageRankResult(pr, int(it),
                           [float(r) for r in res_host if r >= 0.0])
 

@@ -27,6 +27,7 @@ module only owns the artifact, the cache and the serialization.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import time
@@ -36,6 +37,7 @@ from typing import Any, Optional
 import numpy as np
 
 from ..graphs.formats import Graph
+from ..obs.trace import phase
 from .partition import Partitioning
 from .png import BlockedPNG, GatherSchedule, PNGLayout, build_png
 
@@ -286,6 +288,11 @@ class PlanCacheStats:
     png_builds: int = 0
     png_hits: int = 0
     plan_patches: int = 0    # incremental patches (stream/patch.py)
+    # cumulative host seconds of each build phase (``build_phase``)
+    check_s: float = 0.0            # validate + fingerprint
+    png_build_s: float = 0.0        # core/png.py build_png
+    schedule_build_s: float = 0.0   # gather schedules
+    upload_s: float = 0.0           # streams to the device
 
 
 _PLAN_CACHE: dict[tuple, GraphPlan] = {}
@@ -343,7 +350,8 @@ def _touch(cache: dict, key) -> None:
 
 
 def plan_cache_stats() -> PlanCacheStats:
-    """Live build/hit counters (tests assert build count == 1)."""
+    """Live build/hit counters (tests assert build count == 1) and the
+    cumulative seconds of each build phase."""
     return _STATS
 
 
@@ -351,9 +359,30 @@ def clear_plan_cache() -> None:
     """Drop every cached plan and PNG layout and reset the counters."""
     _PLAN_CACHE.clear()
     _PNG_CACHE.clear()
-    _STATS.plan_builds = _STATS.plan_hits = 0
-    _STATS.png_builds = _STATS.png_hits = 0
-    _STATS.plan_patches = 0
+    for f in dataclasses.fields(_STATS):
+        setattr(_STATS, f.name, f.default)
+
+
+# build phase -> (profiler span, PlanCacheStats field it adds to)
+_BUILD_PHASES = {"png": ("repro.plan.png", "png_build_s"),
+                 "schedule": ("repro.plan.schedule", "schedule_build_s"),
+                 "upload": ("repro.plan.upload", "upload_s")}
+
+
+@contextlib.contextmanager
+def build_phase(name: str):
+    """One phase of a plan build (``png``, ``schedule``, ``upload``):
+    a ``repro.plan.<name>`` profiler span, its host seconds added to
+    the phase's ``PlanCacheStats`` field.  (``repro.plan.check`` is
+    ``build_plan``'s own: it counts only when the cache misses.)"""
+    span, field = _BUILD_PHASES[name]
+    t0 = time.perf_counter()
+    try:
+        with phase(span):
+            yield
+    finally:
+        setattr(_STATS, field,
+                getattr(_STATS, field) + time.perf_counter() - t0)
 
 
 def peek_plan(fp: str, config: PlanConfig) -> Optional[GraphPlan]:
@@ -453,7 +482,8 @@ def shared_png(g: Graph, part_size: int) -> PNGLayout:
         return png
     _STATS.png_builds += 1
     t0 = time.perf_counter()
-    png = build_png(g, Partitioning(g.num_nodes, part_size))
+    with build_phase("png"):
+        png = build_png(g, Partitioning(g.num_nodes, part_size))
     _bounded_insert(_PNG_CACHE, MAX_CACHED_PNGS, key, png)
     notify_plan_event("png_build", part_size=part_size,
                       n=g.num_nodes, m=g.num_edges,
@@ -467,9 +497,11 @@ def build_plan(g: Graph, config: PlanConfig | None = None) -> GraphPlan:
     ``build_plan``."""
     from .backends import get_backend, normalize_config
     from ..graphs.formats import validate_graph
-    validate_graph(g)     # crisp ValueError on out-of-range ids, not
-    cfg = normalize_config(g, config or PlanConfig())  # an index crash
-    fp = graph_fingerprint(g)
+    t0 = time.perf_counter()
+    with phase("repro.plan.check"):
+        validate_graph(g)     # crisp ValueError on out-of-range ids,
+        cfg = normalize_config(g, config or PlanConfig())  # not a crash
+        fp = graph_fingerprint(g)
     key = (fp, cfg)
     plan = _PLAN_CACHE.get(key)
     if plan is not None:
@@ -479,6 +511,7 @@ def build_plan(g: Graph, config: PlanConfig | None = None) -> GraphPlan:
                           fp=fp[:12])
         return plan
     _STATS.plan_builds += 1
+    _STATS.check_s += time.perf_counter() - t0
     t0 = time.perf_counter()
     if cfg.reorder != "none":
         # build every layout on the RELABELED graph (that's the whole

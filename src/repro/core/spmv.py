@@ -126,7 +126,8 @@ def bvgas_gather(bins: jnp.ndarray, dst: jnp.ndarray,
 def pcpm_scatter(update_src: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     """Scatter: ONE update per (src, dst-partition) — the PNG compression.
     Update bins are m/r entries instead of m."""
-    return x[update_src]
+    with jax.named_scope("pcpm.scatter"):
+        return x[update_src]
 
 
 @partial(jax.jit, static_argnames=("num_nodes",))
@@ -155,21 +156,27 @@ def pcpm_gather_blocked(update_bins: jnp.ndarray, eui_padded: jnp.ndarray,
     element-wise scatter-add, which XLA:CPU executes serially.  ~9x
     faster than the flat ``pcpm_gather`` at bench scale, identical to
     f32 rounding.
+
+    Named scopes (the profile's ``tf_op``): ``pcpm.expand`` is the
+    per-arc read of the update bins, ``pcpm.reduce`` the prefix sums,
+    piece differences and per-destination sum.
     """
-    vals = update_bins[eui_padded]                  # (Mp,) or (Mp, d)
-    nb = eui_padded.shape[0] // block
-    local = jnp.cumsum(
-        vals.reshape((nb, block) + vals.shape[1:]), axis=1
-    ).reshape(vals.shape)
-    lead = local[piece_end]
-    prev = local[jnp.maximum(piece_start - 1, 0)]
-    at_block_start = piece_start % block == 0
-    if vals.ndim > 1:
-        at_block_start = at_block_start[:, None]
-    piece_sum = lead - jnp.where(at_block_start, 0, prev)
-    return jax.ops.segment_sum(piece_sum, piece_dst,
-                               num_segments=num_nodes + 1,
-                               indices_are_sorted=True)[:num_nodes]
+    with jax.named_scope("pcpm.expand"):
+        vals = update_bins[eui_padded]              # (Mp,) or (Mp, d)
+    with jax.named_scope("pcpm.reduce"):
+        nb = eui_padded.shape[0] // block
+        local = jnp.cumsum(
+            vals.reshape((nb, block) + vals.shape[1:]), axis=1
+        ).reshape(vals.shape)
+        lead = local[piece_end]
+        prev = local[jnp.maximum(piece_start - 1, 0)]
+        at_block_start = piece_start % block == 0
+        if vals.ndim > 1:
+            at_block_start = at_block_start[:, None]
+        piece_sum = lead - jnp.where(at_block_start, 0, prev)
+        return jax.ops.segment_sum(piece_sum, piece_dst,
+                                   num_segments=num_nodes + 1,
+                                   indices_are_sorted=True)[:num_nodes]
 
 
 @partial(jax.jit, static_argnames=("num_nodes", "fused"))

@@ -1,6 +1,6 @@
 """Unified observability layer (DESIGN.md §14).
 
-One ``Observability`` bundle ties the three instruments together:
+One ``Observability`` bundle ties the instruments together:
 
 - ``tracer``/``recorder`` — explicit-parent span tracing into a
   bounded flight-recorder ring (obs/trace.py), threaded through the
@@ -9,33 +9,61 @@ One ``Observability`` bundle ties the three instruments together:
   cross-cutting counters/gauges/histograms report into; per-scheduler
   ``ServeMetrics`` keep their OWN registries (reconciliation is
   per-scheduler) and the gateway scrape endpoint merges all of them.
-- ``comm`` — measured-vs-model communication accounting (obs/comm.py).
 
 Off by default: nothing constructs a bundle unless
 ``EngineConfig(observe=True)`` / ``Session.observe()`` /
 ``SlotScheduler(obs=...)`` asks, and every hot-path hook is a single
-``is None`` branch.
+``is None`` branch.  What is always on costs nothing per iteration:
+the named scopes of the device pass (compile-time metadata) and one
+profiler annotation per coarse phase (``obs.trace.phase``).
 """
 from __future__ import annotations
 
 import itertools
 import os
 import threading
+import weakref
 from typing import Optional
 
-from .comm import CommAccountant, CommBreakdown, measure_plan, vs_model
+import jax
+
+from .comm import CommBreakdown, measure_plan, vs_model
 from .metrics import (DEFAULT_BUCKETS, Counter, Gauge, Histogram,
                       MetricsRegistry, render_prometheus)
 from .trace import (TRACE_SCHEMA_VERSION, FlightRecorder, QuerySpans,
-                    Span, SpanRecord, Tracer)
+                    Span, SpanRecord, Tracer, now_ns, phase)
 
 __all__ = [
     "Observability", "Tracer", "Span", "SpanRecord", "QuerySpans",
     "FlightRecorder", "MetricsRegistry", "Counter", "Gauge",
     "Histogram", "render_prometheus", "DEFAULT_BUCKETS",
-    "CommAccountant", "CommBreakdown", "measure_plan", "vs_model",
-    "TRACE_SCHEMA_VERSION",
+    "CommBreakdown", "measure_plan", "vs_model",
+    "TRACE_SCHEMA_VERSION", "now_ns", "phase",
 ]
+
+# JAX reports each backend compile as a duration event; one listener
+# per process fans it out to the live bundles (held weakly).
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_COMPILE_OBSERVERS: "weakref.WeakSet" = weakref.WeakSet()
+_compile_listener_lock = threading.Lock()
+_compile_listener_on = False
+
+
+def _on_duration(event: str, duration_s: float, **kwargs) -> None:
+    if event != COMPILE_EVENT:
+        return
+    for obs in list(_COMPILE_OBSERVERS):
+        obs.compile_event(duration_s, fun=kwargs.get("fun_name"))
+
+
+def _watch_compiles(obs) -> None:
+    global _compile_listener_on
+    with _compile_listener_lock:
+        if not _compile_listener_on:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_duration)
+            _compile_listener_on = True
+    _COMPILE_OBSERVERS.add(obs)
 
 
 class Observability:
@@ -47,7 +75,6 @@ class Observability:
         self.recorder = FlightRecorder(capacity)
         self.tracer = Tracer(self.recorder, **kw)
         self.registry = MetricsRegistry()
-        self.comm = CommAccountant(registry=self.registry)
         self.dump_dir = dump_dir
         self._dump_seq = itertools.count(1)
         self._dump_lock = threading.Lock()
@@ -56,6 +83,7 @@ class Observability:
         from ..core import plan as _plan
         self._plan_mod = _plan
         _plan.add_plan_observer(self)
+        _watch_compiles(self)
 
     # ------------------------------------------------------------- events
     def plan_event(self, name: str, **attrs) -> None:
@@ -64,6 +92,13 @@ class Observability:
         self.registry.counter("plan_events_total",
                               "plan build/hit/patch events",
                               event=name).inc()
+
+    def compile_event(self, duration_s: float, fun=None) -> None:
+        """Callback target for JAX's backend-compile duration event."""
+        self.tracer.event("compile", trace="plan", fun=fun,
+                          duration_s=duration_s)
+        self.registry.counter("xla_compiles_total",
+                              "XLA backend compiles").inc()
 
     # -------------------------------------------------------------- dumps
     def dump(self, path: str) -> str:
@@ -97,7 +132,6 @@ class Observability:
 
     def stats(self) -> dict:
         return {"metrics": self.registry.to_json(),
-                "comm": self.comm.summary(),
                 "flight_recorder": {
                     "held": len(self.recorder),
                     "recorded": self.recorder.recorded,
@@ -106,3 +140,4 @@ class Observability:
 
     def close(self) -> None:
         self._plan_mod.remove_plan_observer(self)
+        _COMPILE_OBSERVERS.discard(self)

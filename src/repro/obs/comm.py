@@ -1,15 +1,12 @@
-"""Measured communication accounting (DESIGN.md §14).
+"""Plan stream accounting (DESIGN.md §14).
 
 ``core/comm_model.py`` carries the paper's §V napkin math (eqs. 3-10,
 the Table 2 traffic model behind Fig. 8 / Table 6) — a PREDICTION
-from (n, m, k, r).  This module produces the matching MEASUREMENT
-from a live system: enumerate the arrays one SpMV pass actually
-streams — at their real, padded, on-device sizes — and multiply by
-executed pass counts reported by the solvers.  Predicted and measured
-land side by side in benchmark ``comm/`` rows, which is how ROADMAP
-items 3-5 (zero-recompile rebinds, overlapped comms, TPU kernels) get
-scored against the paper's 1.7x DRAM-traffic claim instead of against
-the model alone.
+from (n, m, k, r).  This module counts the same quantity off a built
+plan: the arrays one SpMV pass streams, at their real, padded,
+on-device sizes.  It is a count from shapes, not a measurement of the
+device; predicted and counted land side by side in benchmark
+``comm/`` rows.
 
 Accounting rules (full derivation in DESIGN.md §14):
 
@@ -30,8 +27,6 @@ Accounting rules (full derivation in DESIGN.md §14):
 from __future__ import annotations
 
 import dataclasses
-import threading
-from typing import Optional
 
 from ..core import comm_model
 
@@ -203,95 +198,3 @@ def vs_model(plan, ncols: int = 1) -> dict:
             plan, ncols=ncols).dram_bytes
         out["ncols"] = ncols
     return out
-
-
-class CommAccountant:
-    """Accumulates executed-pass counts against per-plan breakdowns.
-
-    Solvers report ``record_solve(plan, iterations)`` (one pass per
-    iteration) and the SlotScheduler reports ``record_pass`` per
-    dispatched device chunk with the chunk's iteration count and the
-    batch width B.  Totals land in the shared registry under
-    ``comm_*`` and in ``summary()`` next to the model prediction.
-    """
-
-    def __init__(self, registry=None):
-        self._lock = threading.Lock()
-        self._registry = registry
-        # (id(plan), ncols) -> CommBreakdown — plans are immutable and
-        # identity-hashed, so id() is a stable key for a live plan.
-        self._breakdowns: dict = {}
-        self._plans: dict = {}      # keep plans alive while accounted
-        # method -> accumulated {passes, dram_bytes, gather, scatter}
-        self._totals: dict = {}
-        # (id(plan), ncols) -> (passes Counter, bytes Counter) — the
-        # registry lookup (sorted-label key + family dict walk) is the
-        # expensive part of a scrape-live counter; record_pass runs
-        # once per device chunk, so the handles are resolved once
-        self._counters: dict = {}
-
-    def _breakdown(self, plan, ncols: int) -> Optional[CommBreakdown]:
-        key = (id(plan), int(ncols))
-        bd = self._breakdowns.get(key)
-        if bd is None:
-            try:
-                bd = measure_plan(plan, ncols=ncols)
-            except ValueError:
-                return None          # sharded/exotic plan: skip
-            self._breakdowns[key] = bd
-            self._plans[key] = plan
-            if self._registry is not None:
-                self._counters[key] = (
-                    self._registry.counter(
-                        "comm_passes_total",
-                        "executed SpMV passes", method=bd.method),
-                    self._registry.counter(
-                        "comm_dram_bytes_total",
-                        "measured DRAM-model bytes moved",
-                        method=bd.method))
-        return bd
-
-    def record_pass(self, plan, *, iters: int = 1,
-                    ncols: int = 1) -> None:
-        if iters <= 0:
-            return
-        key = (id(plan), int(ncols))
-        with self._lock:
-            bd = self._breakdown(plan, ncols)
-            if bd is None:
-                return
-            t = self._totals.setdefault(
-                bd.method, {"passes": 0, "dram_bytes": 0,
-                            "gather_ops": 0, "scatter_ops": 0})
-            t["passes"] += iters
-            t["dram_bytes"] += iters * bd.dram_bytes
-            t["gather_ops"] += iters * bd.gather_ops
-            t["scatter_ops"] += iters * bd.scatter_ops
-            handles = self._counters.get(key)
-        if handles is not None:
-            handles[0].inc(iters)
-            handles[1].inc(iters * bd.dram_bytes)
-
-    def record_solve(self, plan, iterations: int,
-                     ncols: int = 1) -> None:
-        self.record_pass(plan, iters=int(iterations), ncols=ncols)
-
-    def summary(self) -> dict:
-        """Accumulated measured traffic per method, each with the
-        model prediction scaled by the same pass count."""
-        with self._lock:
-            totals = {k: dict(v) for k, v in self._totals.items()}
-            plans = dict(self._plans)
-        out = {}
-        for method, t in totals.items():
-            row = dict(t)
-            plan = next((p for (pid, nc), p in plans.items()
-                         if p.config.method == method), None)
-            if plan is not None and t["passes"]:
-                cmp_ = vs_model(plan)
-                row["model_dram_bytes"] = (cmp_["model_bytes_per_iter"]
-                                           * t["passes"])
-                row["bytes_per_pass"] = t["dram_bytes"] / t["passes"]
-                row["ratio_vs_model"] = cmp_["ratio"]
-            out[method] = row
-        return out

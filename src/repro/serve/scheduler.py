@@ -375,18 +375,9 @@ class SlotScheduler:
             self.trace_count += 1     # increments only at trace time
 
         state_spec, act_spec, tol_spec, bud_spec, inv_spec = self._specs
-        t0 = time.perf_counter()
         step_c = compile_bound(step, state_spec, state_spec, act_spec,
                                tol_spec, bud_spec, inv_spec,
                                on_trace=counted_step)
-        if self.obs is not None:
-            # trace_count/rebind_count were only attributes until now;
-            # this makes every XLA stepper compile a recorded event
-            self.obs.tracer.event(
-                "xla_compile", trace="plan", kind="stepper",
-                method=engine.method, slots=self.slots,
-                trace_count=self.trace_count,
-                duration_s=time.perf_counter() - t0)
         return step_c, inv_deg
 
     def apply_delta(self, delta, *, g_new: Graph | None = None) -> None:
@@ -870,13 +861,7 @@ class SlotScheduler:
         took = np.asarray(took)
         res = np.asarray(res)
         if csp is not None:
-            iters = int(took.max()) if took.size else 0
-            csp.end(iters=iters)
-            # measured bytes: the stepper computes the full (n, B)
-            # state per pass regardless of the freeze mask — B columns
-            # is the honest ncols (obs/comm.py)
-            self.obs.comm.record_pass(self.engine.plan, iters=iters,
-                                      ncols=self.slots)
+            csp.end(iters=int(took.max()) if took.size else 0)
         with self._lock:
             self._iters += took
             self._update_pressure(time.perf_counter() - t0,

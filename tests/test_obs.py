@@ -8,9 +8,9 @@ integration storms:
   Prometheus text format down to the line.
 - tracer/flight recorder: explicit-parent nesting, bounded ring with
   drop accounting, exactly-once ``end()``, JSONL dump format.
-- comm accounting: measured bytes from real plan geometry vs the
-  paper's §V model — pcpm must land within 2x of eq. 5 (the headline
-  acceptance bound), and the per-stream breakdown must reconcile.
+- plan stream accounting: bytes counted from real plan geometry vs
+  the paper's §V model — pcpm must land within 2x of eq. 5, and the
+  per-stream breakdown must reconcile.
 - the serving integration: a PR 9-shaped concurrent mixed push/stepper
   storm with observability ON must yield one complete, well-nested
   span tree per query with exactly one terminal event, keep
@@ -28,7 +28,6 @@ from repro.core.plan import PlanConfig, build_plan, clear_plan_cache
 from repro.graphs import generators
 from repro.obs import (FlightRecorder, MetricsRegistry, Observability,
                        QuerySpans, Tracer, measure_plan, vs_model)
-from repro.obs.comm import CommAccountant
 from repro.reliability import (FaultInjector, FaultPlan, FaultSpec,
                                ResilienceConfig)
 from repro.serve import SlotScheduler
@@ -197,7 +196,7 @@ class TestTracer:
         path = tr.recorder.dump(str(tmp_path / "f.jsonl"))
         lines = open(path).read().splitlines()
         header = json.loads(lines[0])
-        assert header == {"schema": 1, "recorded": 2, "dropped": 0,
+        assert header == {"schema": 2, "recorded": 2, "dropped": 0,
                           "capacity": 8, "held": 2}
         rows = [json.loads(ln) for ln in lines[1:]]
         assert [r["name"] for r in rows] == ["a", "b"]
@@ -237,7 +236,7 @@ class TestTracer:
 
 
 # ---------------------------------------------------------------------------
-# Comm accounting
+# Plan stream accounting
 # ---------------------------------------------------------------------------
 class TestCommAccounting:
     def test_pcpm_measured_within_2x_of_model(self):
@@ -274,22 +273,6 @@ class TestCommAccounting:
         b1 = measure_plan(plan, ncols=1).dram_bytes
         b8 = measure_plan(plan, ncols=8).dram_bytes
         assert b1 < b8 < 8 * b1
-
-    def test_accountant_accumulates_and_skips_empty(self):
-        g = generators.rmat(8, 8, seed=1)
-        plan = build_plan(g, PlanConfig(method="pcpm", part_size=64))
-        reg = MetricsRegistry()
-        acc = CommAccountant(registry=reg)
-        acc.record_pass(plan, iters=0)          # no-op
-        acc.record_solve(plan, 10)
-        acc.record_pass(plan, iters=5)
-        s = acc.summary()["pcpm"]
-        assert s["passes"] == 15
-        assert s["dram_bytes"] == 15 * s["bytes_per_pass"]
-        assert s["ratio_vs_model"] == pytest.approx(
-            s["dram_bytes"] / s["model_dram_bytes"])
-        assert reg.counter_value("comm_passes_total",
-                                 method="pcpm") == 15
 
 
 # ---------------------------------------------------------------------------
@@ -385,10 +368,11 @@ class TestSessionObserve:
         res = sess.pagerank(num_iterations=5)
         st = sess.stats()
         assert st["plan_cache"]["plan_builds"] >= 1
-        assert st["obs"]["comm"]["pcpm"]["passes"] == res.iterations
+        assert set(st["obs"]) == {"metrics", "flight_recorder"}
         assert st["obs"]["flight_recorder"]["recorded"] >= 1
-        names = [r.name for r in obs.recorder.snapshot()]
-        assert "solve" in names
+        (solve,) = [r for r in obs.recorder.snapshot()
+                    if r.name == "repro.solve"]
+        assert solve.attrs["iterations"] == res.iterations
 
     def test_config_observe_traces_build_and_solve(self):
         clear_plan_cache()
@@ -398,7 +382,7 @@ class TestSessionObserve:
         names = [r.name for r in sess.obs.recorder.snapshot()]
         # the bundle attaches BEFORE the plan builds, so the session's
         # own preprocessing is on the record
-        assert "plan_build" in names and "solve" in names
+        assert "plan_build" in names and "repro.solve" in names
 
     def test_crash_dump_on_quarantine(self, g, tmp_path):
         """PR 6's resilience path is the forensics moment: a poisoned
@@ -417,7 +401,7 @@ class TestSessionObserve:
             dumps = list(tmp_path.glob("flight-*.jsonl"))
             assert len(dumps) == 1
             lines = dumps[0].read_text().splitlines()
-            assert json.loads(lines[0])["schema"] == 1
+            assert json.loads(lines[0])["schema"] == 2
             assert any(json.loads(ln)["name"] == "crash_dump"
                        for ln in lines[1:])
             assert obs.registry.counter_value("crash_dumps_total") == 1
@@ -608,5 +592,5 @@ class TestGatewayObserved:
         assert "# TYPE serve_terminals_total counter" in text
         assert 'serve_terminals_total{graph="default"} 1' in text
         assert "gateway_cache_entries" in text
-        assert "comm_passes_total" in text      # obs registry merged
+        assert "xla_compiles_total" in text     # obs registry merged
         assert "trace_count" in text
