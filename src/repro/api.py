@@ -164,9 +164,27 @@ class Session:
         out = {"plan_cache": dataclasses.asdict(plan_cache_stats()),
                "method": self.config.method,
                "n": self.plan.num_nodes, "m": self.plan.num_edges}
+        expand = self._expand_stats()
+        if expand is not None:
+            out["expand"] = expand
         if self._obs is not None:
             out["obs"] = self._obs.stats()
         return out
+
+    def _expand_stats(self):
+        """The pcpm expand's counters (``backends.expand_stats``), or
+        None for other methods; reported into the metrics registry as
+        gauges when observing."""
+        if self.plan.method != "pcpm":
+            return None
+        from .core.backends import expand_stats
+        stats = expand_stats(self.plan)
+        if self._obs is not None:
+            for name, value in stats.items():
+                self._obs.registry.gauge(
+                    name, "pcpm expand, per pass of one rank column"
+                ).set(value)
+        return stats
 
     # ---------------------------------------------------------- deltas
     def apply_delta(self, delta) -> "Session":
@@ -252,6 +270,8 @@ class Session:
                     dangling=kw["dangling"], tol=tol, max_push=budget)
             else:
                 res = pagerank(self.graph, engine=self.engine, **kw)
+                if self._obs is not None:
+                    self._expand_stats()
             if sp is not None:
                 sp.annotate(iterations=res.iterations, residual=float(
                     (res.residuals or [np.inf])[-1]))

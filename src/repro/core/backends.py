@@ -294,9 +294,11 @@ def _build_pdpr(g: Graph, cfg: PlanConfig) -> GraphPlan:
 
 def _upload(*arrays):
     """The plan's host streams on the device, waited for (so the
-    ``upload`` phase holds the transfer, not just its enqueue)."""
+    ``upload`` phase holds the transfer, not just its enqueue); a None
+    stays None."""
     with build_phase("upload"):
-        return jax.block_until_ready(tuple(map(jnp.asarray, arrays)))
+        return jax.block_until_ready(tuple(
+            None if a is None else jnp.asarray(a) for a in arrays))
 
 
 def _sched_device(plan: GraphPlan):
@@ -362,19 +364,17 @@ def _bvgas_device(plan: GraphPlan):
     return dev
 
 
-def _scatter_gather(src, eui, ps, pe, pd, x, *, scatter, num_nodes: int,
-                    block: int):
-    """bvgas and pcpm alike: the method's scatter into bins, then the
-    blocked gather."""
-    return _gather(eui, ps, pe, pd, scatter(src, x), num_nodes=num_nodes,
-                   block=block)
+def _bvgas_pass(src, eui, ps, pe, pd, x, *, num_nodes: int, block: int):
+    """bvgas: the scatter into per-edge bins, then the blocked gather."""
+    from .spmv import bvgas_scatter
+    return _gather(eui, ps, pe, pd, bvgas_scatter(src, x),
+                   num_nodes=num_nodes, block=block)
 
 
 def _spmv_bvgas(plan: GraphPlan):
-    from .spmv import bvgas_scatter
-    return _streams_fn(_scatter_gather, _bvgas_device(plan),
-                       *_sched_device(plan), scatter=bvgas_scatter,
-                       num_nodes=plan.num_nodes, block=plan.schedule.block)
+    return _streams_fn(_bvgas_pass, _bvgas_device(plan),
+                       *_sched_device(plan), num_nodes=plan.num_nodes,
+                       block=plan.schedule.block)
 
 
 def _phases_bvgas(plan: GraphPlan):
@@ -402,21 +402,70 @@ def _pcpm_device(plan: GraphPlan):
     if dev is None:
         s = plan.schedule
         dev = _upload(plan.png.update_src, s.edge_update_idx_padded,
-                      s.piece_start, s.piece_end, s.piece_dst)
+                      s.piece_start, s.piece_end, s.piece_dst,
+                      s.window_start)
         plan._device["pcpm"] = dev
     return dev
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def window_engages(schedule: GatherSchedule) -> bool:
+    """Whether the pcpm pass of one rank column expands through the
+    VMEM-window kernel (``kernels/pcpm_expand``): on a TPU, where the
+    schedule has windows and the window fits the kernel's VMEM budget.
+    XLA's element gather runs otherwise, and for several columns."""
+    from ..kernels.pcpm_expand import fits
+    return (schedule.window_start is not None
+            and fits(schedule.window_rows, schedule.kernel_block)
+            and _on_tpu())
+
+
+def _pcpm_pass(upd, eui, ps, pe, pd, win, x, *, num_nodes: int,
+               block: int, window_rows: int, windowed: bool):
+    """The pcpm pass: scatter, expand, reduce.  The expand takes the
+    window kernel where ``windowed`` and ``x`` is one column."""
+    from .spmv import (pcpm_expand, pcpm_expand_windowed, pcpm_reduce,
+                       pcpm_scatter)
+    bins = pcpm_scatter(upd, x)
+    if windowed and x.ndim == 1:
+        vals = pcpm_expand_windowed(bins, eui, win,
+                                    window_rows=window_rows)
+    else:
+        vals = pcpm_expand(bins, eui)
+    return pcpm_reduce(vals, ps, pe, pd, num_nodes=num_nodes, block=block)
+
+
 def _spmv_pcpm(plan: GraphPlan):
-    from .spmv import pcpm_scatter
-    return _streams_fn(_scatter_gather, *_pcpm_device(plan),
-                       scatter=pcpm_scatter, num_nodes=plan.num_nodes,
-                       block=plan.schedule.block)
+    s = plan.schedule
+    return _streams_fn(_pcpm_pass, *_pcpm_device(plan),
+                       num_nodes=plan.num_nodes, block=s.block,
+                       window_rows=s.window_rows,
+                       windowed=window_engages(s))
+
+
+def expand_stats(plan: GraphPlan) -> dict:
+    """Counts of a pcpm plan's expand, per pass of one rank column:
+    the share of arcs gathered from a resident window (1.0 where the
+    window kernel engages, else 0), the window fills, and the pad
+    arcs the kernel blocks add over the arcs."""
+    s = plan.schedule
+    on = window_engages(s)
+    loads = 0
+    if on:
+        w = s.window_start
+        loads = 1 + int(np.count_nonzero(w[1:] != w[:-1]))
+    pad = len(s.edge_update_idx_padded) - s.num_edges
+    return {"pcpm_expand_window_share": 1.0 if on else 0.0,
+            "pcpm_expand_window_loads": loads,
+            "pcpm_expand_pad_share": pad / max(s.num_edges, 1)}
 
 
 def _phases_pcpm(plan: GraphPlan):
     from .spmv import pcpm_gather_blocked, pcpm_scatter
-    upd, eui, ps, pe, pd = _pcpm_device(plan)
+    upd, eui, ps, pe, pd, _ = _pcpm_device(plan)
     n, blk = plan.num_nodes, plan.schedule.block
     return (lambda x: pcpm_scatter(upd, x),
             lambda bins: pcpm_gather_blocked(bins, eui, ps, pe, pd,

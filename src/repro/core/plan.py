@@ -169,11 +169,14 @@ class GraphPlan:
                            "png/edge_offsets": p.edge_offsets})
         if self.schedule is not None:
             s = self.schedule
-            meta["schedule"] = {"block": s.block, "num_edges": s.num_edges}
+            meta["schedule"] = {"block": s.block, "num_edges": s.num_edges,
+                                "window_rows": s.window_rows}
             arrays.update({"sched/eui": s.edge_update_idx_padded,
                            "sched/piece_start": s.piece_start,
                            "sched/piece_end": s.piece_end,
                            "sched/piece_dst": s.piece_dst})
+            if s.window_start is not None:
+                arrays["sched/window_start"] = s.window_start
         if self.blocked is not None:
             b = self.blocked
             meta["blocked"] = {"part_size": b.part_size,
@@ -233,7 +236,9 @@ class GraphPlan:
             kw["schedule"] = GatherSchedule(
                 int(s["block"]), int(s["num_edges"]), z["sched/eui"],
                 z["sched/piece_start"], z["sched/piece_end"],
-                z["sched/piece_dst"])
+                z["sched/piece_dst"],
+                z["sched/window_start"] if "sched/window_start" in z
+                else None, int(s.get("window_rows", 0)))
         if "blocked" in meta:
             b = meta["blocked"]
             kw["blocked"] = BlockedPNG(
@@ -607,6 +612,8 @@ def plan_nbytes(plan: GraphPlan) -> int:
         s = plan.schedule
         arrays += [s.edge_update_idx_padded, s.piece_start,
                    s.piece_end, s.piece_dst]
+        if s.window_start is not None:
+            arrays.append(s.window_start)
     if plan.blocked is not None:
         b = plan.blocked
         arrays += [b.update_src, b.edge_update_local, b.edge_dst_local]

@@ -138,35 +138,52 @@ class GatherSchedule:
       edge_update_idx_padded[Mp]  update pointer, M padded to block mult
       piece_start[P0], piece_end[P0]   inclusive run bounds (flat index)
       piece_dst[P0]               global destination, pad = num_nodes
+
+    pcpm schedules also carry the expand kernel's windows
+    (``kernels/pcpm_expand``): ``window_start[b]`` is the first
+    128-lane row of the update bins that kernel block ``b`` reads, all
+    within ``window_rows`` rows.  Other schedules have none.
     """
     block: int
     num_edges: int               # un-padded M
-    edge_update_idx_padded: np.ndarray  # (Mp,) int32, pad = 0 (inert)
+    edge_update_idx_padded: np.ndarray  # (Mp,) int32, pads inert
     piece_start: np.ndarray      # (P0,) int32
     piece_end: np.ndarray        # (P0,) int32
     piece_dst: np.ndarray        # (P0,) int32, pad = num_nodes
+    window_start: np.ndarray | None = None   # (Mp / kernel block,) int32
+    window_rows: int = 0
 
     @property
     def num_blocks(self) -> int:
         return len(self.edge_update_idx_padded) // self.block
 
+    @property
+    def kernel_block(self) -> int:
+        """Arcs per expand-kernel block (0 without windows)."""
+        if self.window_start is None:
+            return 0
+        return len(self.edge_update_idx_padded) // len(self.window_start)
+
 
 def flat_gather_schedule(edge_update_idx: np.ndarray,
                          edge_dst: np.ndarray, *, num_nodes: int,
-                         block: int = 256, pad_update: int = 0
+                         block: int = 256, pad_update: int = 0,
+                         length_multiple: int | None = None
                          ) -> tuple[np.ndarray, np.ndarray, np.ndarray,
                                     np.ndarray]:
     """Schedule-build core over raw dst-sorted streams.
 
     Returns ``(eui_padded, piece_start, piece_end, piece_dst)`` with
-    the stream padded to a ``block`` multiple; pad edges point at
+    the stream padded to a multiple of ``length_multiple`` (default
+    ``block``, of which it is a multiple); pad edges point at
     ``pad_update`` and carry the ``num_nodes`` sentinel destination so
-    the final segment-sum drops them.  Shared by the single-device PNG
+    the final segment-sum drops them.  Shared by every single-device
     schedule and the per-shard schedule of ``core/distributed.py``
     (whose pad update is the receive buffer's zero slot).
     """
     m = len(edge_dst)
-    mp = -(-max(m, 1) // block) * block
+    mult = length_multiple or block
+    mp = -(-max(m, 1) // mult) * mult
     dst_pad = np.full(mp, num_nodes, dtype=np.int32)
     dst_pad[:m] = edge_dst
     eui_pad = np.full(mp, pad_update, dtype=np.int32)
@@ -181,20 +198,56 @@ def flat_gather_schedule(edge_update_idx: np.ndarray,
     return eui_pad, starts, ends, dst_pad[starts]
 
 
+# arcs per expand-kernel block: 64 rows of 128 lanes
+KERNEL_BLOCK = 8192
+LANES, SUBLANES = 128, 8
+
+
+def _round_up(x: int, mult: int) -> int:
+    return -(-x // mult) * mult
+
+
 def build_gather_schedule(layout: PNGLayout, *,
                           block: int = 256) -> GatherSchedule:
     """Cut the dst-sorted gather stream into per-block runs.
 
     A new piece starts wherever the destination changes or a block
-    boundary is crossed; pad edges (index >= M) point at update 0 but
-    carry the ``num_nodes`` sentinel destination, so the final
-    segment-sum drops them.
+    boundary is crossed; pad edges (index >= M) carry the ``num_nodes``
+    sentinel destination, so the final segment-sum drops them.
+
+    Where ``block`` divides the expand kernel's block, the stream is
+    padded to whole kernel blocks and each gets a window: block ``b``
+    reads bin rows ``[window_start[b], window_start[b] + window_rows)``
+    of the bins' ``(rows, 128)`` view.  Its window starts at the
+    (8, 128) tile holding the first update of its first arc's
+    partition, moved down where needed to end inside the bins (rows
+    counted in whole tiles), so consecutive blocks share a window until
+    a block starts in the next partition.  ``window_rows`` covers the
+    widest block: a block that runs into the next partition reads both
+    partitions' updates.  Pad edges point at their window's first
+    update.
     """
+    kb = KERNEL_BLOCK if KERNEL_BLOCK % block == 0 else 0
     eui_pad, starts, ends, piece_dst = flat_gather_schedule(
         layout.edge_update_idx, layout.edge_dst,
-        num_nodes=layout.num_nodes, block=block, pad_update=0)
-    return GatherSchedule(block, layout.num_edges, eui_pad, starts,
-                          ends, piece_dst)
+        num_nodes=layout.num_nodes, block=block, length_multiple=kb)
+    if not kb:
+        return GatherSchedule(block, layout.num_edges, eui_pad, starts,
+                              ends, piece_dst)
+    m = layout.num_edges
+    uo, eo = layout.update_offsets, layout.edge_offsets
+    rows = _round_up(max(-(-layout.num_updates // LANES), 1), SUBLANES)
+    first_arc = np.arange(0, len(eui_pad), kb)
+    part = np.minimum(np.searchsorted(eo, first_arc, side="right") - 1,
+                      layout.num_partitions - 1)
+    lo = uo[part] // LANES // SUBLANES * SUBLANES
+    eui_pad[m:] = 0
+    hi = eui_pad.reshape(-1, kb).max(axis=1) // LANES + 1
+    window_rows = _round_up(int((hi - lo).max()), SUBLANES)
+    window_start = np.minimum(lo, rows - window_rows).astype(np.int32)
+    eui_pad[m:] = np.repeat(window_start * LANES, kb)[m:]
+    return GatherSchedule(block, m, eui_pad, starts, ends, piece_dst,
+                          window_start, window_rows)
 
 
 # ---------------------------------------------------------------------------

@@ -144,27 +144,38 @@ def pcpm_gather(update_bins: jnp.ndarray, edge_update_idx: jnp.ndarray,
                                num_segments=num_nodes)
 
 
-@partial(jax.jit, static_argnames=("num_nodes", "block"))
-def pcpm_gather_blocked(update_bins: jnp.ndarray, eui_padded: jnp.ndarray,
-                        piece_start: jnp.ndarray, piece_end: jnp.ndarray,
-                        piece_dst: jnp.ndarray, *, num_nodes: int,
-                        block: int) -> jnp.ndarray:
-    """Hierarchical gather over the dst-sorted stream (DESIGN.md §3).
-
-    Per-block inclusive prefix sums turn each destination's run into a
-    difference of two gathers; only the ~n + M/block run sums hit the
-    element-wise scatter-add, which XLA:CPU executes serially.  ~9x
-    faster than the flat ``pcpm_gather`` at bench scale, identical to
-    f32 rounding.
-
-    Named scopes (the profile's ``tf_op``): ``pcpm.expand`` is the
-    per-arc read of the update bins, ``pcpm.reduce`` the prefix sums,
-    piece differences and per-destination sum.
-    """
+@jax.jit
+def pcpm_expand(update_bins: jnp.ndarray,
+                eui_padded: jnp.ndarray) -> jnp.ndarray:
+    """The per-arc read of the update bins, XLA's element gather
+    (named scope ``pcpm.expand``): (Mp,) or (Mp, d)."""
     with jax.named_scope("pcpm.expand"):
-        vals = update_bins[eui_padded]              # (Mp,) or (Mp, d)
+        return update_bins[eui_padded]
+
+
+@partial(jax.jit, static_argnames=("window_rows",))
+def pcpm_expand_windowed(update_bins: jnp.ndarray, eui_padded: jnp.ndarray,
+                         window_start: jnp.ndarray, *,
+                         window_rows: int) -> jnp.ndarray:
+    """``pcpm_expand`` of one rank column by the Pallas kernel that
+    reads each arc's update from a VMEM-resident window of its
+    destination partition's bins (``kernels/pcpm_expand``)."""
+    from ..kernels.pcpm_expand import window_expand
+    with jax.named_scope("pcpm.expand"):
+        return window_expand(update_bins, eui_padded, window_start,
+                             window_rows=window_rows)
+
+
+@partial(jax.jit, static_argnames=("num_nodes", "block"))
+def pcpm_reduce(vals: jnp.ndarray, piece_start: jnp.ndarray,
+                piece_end: jnp.ndarray, piece_dst: jnp.ndarray, *,
+                num_nodes: int, block: int) -> jnp.ndarray:
+    """The per-destination sum of the expanded values (named scope
+    ``pcpm.reduce``): per-block inclusive prefix sums turn each
+    destination's run into a difference of two gathers; only the
+    ~n + M/block run sums hit the element-wise scatter-add."""
     with jax.named_scope("pcpm.reduce"):
-        nb = eui_padded.shape[0] // block
+        nb = vals.shape[0] // block
         local = jnp.cumsum(
             vals.reshape((nb, block) + vals.shape[1:]), axis=1
         ).reshape(vals.shape)
@@ -177,6 +188,20 @@ def pcpm_gather_blocked(update_bins: jnp.ndarray, eui_padded: jnp.ndarray,
         return jax.ops.segment_sum(piece_sum, piece_dst,
                                    num_segments=num_nodes + 1,
                                    indices_are_sorted=True)[:num_nodes]
+
+
+@partial(jax.jit, static_argnames=("num_nodes", "block"))
+def pcpm_gather_blocked(update_bins: jnp.ndarray, eui_padded: jnp.ndarray,
+                        piece_start: jnp.ndarray, piece_end: jnp.ndarray,
+                        piece_dst: jnp.ndarray, *, num_nodes: int,
+                        block: int) -> jnp.ndarray:
+    """Hierarchical gather over the dst-sorted stream (DESIGN.md §3):
+    ``pcpm_expand`` then ``pcpm_reduce``.  ~9x faster than the flat
+    ``pcpm_gather`` on the CPU at bench scale, identical to f32
+    rounding."""
+    return pcpm_reduce(pcpm_expand(update_bins, eui_padded), piece_start,
+                       piece_end, piece_dst, num_nodes=num_nodes,
+                       block=block)
 
 
 @partial(jax.jit, static_argnames=("num_nodes", "fused"))

@@ -28,6 +28,10 @@ M22 = 16 * N22
 U22 = int(M22 / 3.2)
 PIECES22 = N22 + M22 // DEFAULT_GATHER_BLOCK
 HBM_BYTES = 16 * 10 ** 9       # one v5e chip
+# graph500-22 in LDBC Graphalytics' form, the benchmark's graph: its
+# update count, arcs padded to whole expand-kernel blocks, and a window
+# of 10240 rows (5.2 MB), two of its 37 partitions of 65536 vertices
+NG22, UG22, MPG22, WG22 = 2_395_582, 24_004_616, 15_664 * 8192, 10_240
 
 
 @pytest.fixture(scope="module")
@@ -129,3 +133,91 @@ def test_sharded_loop_compiles_for_four_chips(topo, monkeypatch):
     mem = compiled.memory_analysis()
     per_device = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert per_device < HBM_BYTES, per_device
+
+
+# ---------------------------------------------------------------------------
+# The pcpm expand's window kernel (kernels/pcpm_expand)
+# ---------------------------------------------------------------------------
+def test_windowed_expand_compiles_at_graph500_22(one_chip):
+    from repro.kernels.pcpm_expand import fits, window_expand
+    assert fits(WG22, 8192)
+    fn = functools.partial(window_expand, window_rows=WG22,
+                           interpret=False)
+    args = (jax.ShapeDtypeStruct((UG22,), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((MPG22,), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((MPG22 // 8192,), jnp.int32,
+                                 sharding=one_chip))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _fused_loop_text(spmv, num_nodes, one_chip):
+    """The optimized HLO of the fused 20-pass pcpm loop over ``spmv``,
+    compiled for one described v5e chip."""
+    import types
+    from repro.core.pagerank import fused_power_iteration
+
+    def shape(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    engine = types.SimpleNamespace(_fused_cache={},
+                                   spmv_fn=lambda: spmv)
+    loop = fused_power_iteration(engine, num_iterations=20,
+                                 dangling="redistribute")
+    vec = jax.ShapeDtypeStruct((num_nodes,), jnp.float32,
+                               sharding=one_chip)
+    spmv = jax.tree_util.tree_map(shape, loop.args[0])
+    return loop.func.lower(spmv, vec, vec, vec).compile().as_text()
+
+
+def _custom_calls(text):
+    return [ln for ln in text.splitlines()
+            if "tpu_custom_call" in ln and " custom-call(" in ln]
+
+
+def test_fused_loop_holds_window_kernel_under_expand(one_chip, monkeypatch):
+    """At the benchmark graph's shapes, the fused pcpm loop's expand
+    is the window kernel, under the ``pcpm.expand`` scope."""
+    import numpy as np
+    from repro.core import backends
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def i32(n):
+        return np.broadcast_to(np.int32(0), (n,))
+
+    pieces = NG22 + MPG22 // DEFAULT_GATHER_BLOCK
+    spmv = jax.tree_util.Partial(
+        functools.partial(backends._pcpm_pass, num_nodes=NG22,
+                          block=DEFAULT_GATHER_BLOCK, window_rows=WG22,
+                          windowed=True),
+        i32(UG22), i32(MPG22), i32(pieces), i32(pieces), i32(pieces),
+        i32(MPG22 // 8192))
+    (call,) = _custom_calls(_fused_loop_text(spmv, NG22, one_chip))
+    assert "/pcpm.expand/" in call
+
+
+def test_window_past_budget_keeps_xla_gather(one_chip, monkeypatch):
+    """A plan whose window fits compiles the kernel into the loop; the
+    same plan with a window past the kernel's VMEM budget compiles
+    XLA's gather in its place."""
+    import dataclasses
+    import repro
+    from repro.core import backends
+    from repro.core.plan import clear_plan_cache
+    from repro.graphs import generators
+    from repro.kernels.pcpm_expand.kernel import VMEM_LIMIT
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(backends, "_on_tpu", lambda: True)
+    clear_plan_cache()
+    g = generators.rmat(10, 8, seed=0)
+    plan = repro.open(g, method="pcpm", part_size=256).plan
+    past = VMEM_LIMIT // (4 * LANES)
+    wide = dataclasses.replace(plan, schedule=dataclasses.replace(
+        plan.schedule, window_rows=past), _device={})
+    assert backends.window_engages(plan.schedule)
+    assert not backends.window_engages(wide.schedule)
+    for p, kernels in ((plan, 1), (wide, 0)):
+        text = _fused_loop_text(backends.spmv_fn(p), g.num_nodes,
+                                one_chip)
+        assert len(_custom_calls(text)) == kernels
+    clear_plan_cache()

@@ -51,11 +51,18 @@ class TestRoundTrip:
                     getattr(fresh.png, key), getattr(loaded.png, key))
         if fresh.schedule is not None:
             assert loaded.schedule.block == fresh.schedule.block
+            assert (loaded.schedule.window_rows
+                    == fresh.schedule.window_rows)
+            assert ((loaded.schedule.window_start is None)
+                    == (fresh.schedule.window_start is None))
+            if method == "pcpm":      # the expand kernel's windows
+                assert fresh.schedule.window_start is not None
             for key in ("edge_update_idx_padded", "piece_start",
-                        "piece_end", "piece_dst"):
-                np.testing.assert_array_equal(
-                    getattr(fresh.schedule, key),
-                    getattr(loaded.schedule, key))
+                        "piece_end", "piece_dst", "window_start"):
+                a = getattr(fresh.schedule, key)
+                if a is not None:
+                    np.testing.assert_array_equal(
+                        a, getattr(loaded.schedule, key))
         if fresh.blocked is not None:
             for key in ("update_src", "edge_update_local",
                         "edge_dst_local"):
