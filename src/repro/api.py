@@ -158,10 +158,12 @@ class Session:
     def stats(self) -> dict:
         """One dict joining every cache/observability surface the
         session can see: process-level plan-cache counters (with the
-        cumulative seconds of each build phase), and — when observing
-        — the metrics registry and flight-recorder occupancy."""
+        cumulative seconds of each build phase), the plan's compression
+        ratio r, and — when observing — the metrics registry and
+        flight-recorder occupancy."""
         from .core.plan import plan_cache_stats
         out = {"plan_cache": dataclasses.asdict(plan_cache_stats()),
+               "pcpm_compression_ratio": self._compression_ratio(),
                "method": self.config.method,
                "n": self.plan.num_nodes, "m": self.plan.num_edges}
         expand = self._expand_stats()
@@ -171,6 +173,22 @@ class Session:
             out["obs"] = self._obs.stats()
         return out
 
+    def _gauges(self, values: dict, help: str) -> None:
+        """Report ``values`` as gauges of the metrics registry, when
+        observing."""
+        if self._obs is not None:
+            for name, value in values.items():
+                self._obs.registry.gauge(name, help).set(value)
+
+    def _compression_ratio(self) -> float:
+        """The plan's r, arcs per PCPM update (paper table V;
+        ``GraphPlan.compression_ratio``), also a gauge when
+        observing."""
+        r = self.plan.compression_ratio
+        self._gauges({"pcpm_compression_ratio": r},
+                     "arcs per pcpm update of the plan")
+        return r
+
     def _expand_stats(self):
         """The pcpm expand's counters (``backends.expand_stats``), or
         None for other methods; reported into the metrics registry as
@@ -179,11 +197,7 @@ class Session:
             return None
         from .core.backends import expand_stats
         stats = expand_stats(self.plan)
-        if self._obs is not None:
-            for name, value in stats.items():
-                self._obs.registry.gauge(
-                    name, "pcpm expand, per pass of one rank column"
-                ).set(value)
+        self._gauges(stats, "pcpm expand, per pass of one rank column")
         return stats
 
     # ---------------------------------------------------------- deltas
@@ -271,6 +285,7 @@ class Session:
             else:
                 res = pagerank(self.graph, engine=self.engine, **kw)
                 if self._obs is not None:
+                    self._compression_ratio()
                     self._expand_stats()
             if sp is not None:
                 sp.annotate(iterations=res.iterations, residual=float(

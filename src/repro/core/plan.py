@@ -295,6 +295,7 @@ class PlanCacheStats:
     plan_patches: int = 0    # incremental patches (stream/patch.py)
     # cumulative host seconds of each build phase (``build_phase``)
     check_s: float = 0.0            # validate + fingerprint
+    reorder_s: float = 0.0          # locality relabeling (graphs/reorder.py)
     png_build_s: float = 0.0        # core/png.py build_png
     schedule_build_s: float = 0.0   # gather schedules
     upload_s: float = 0.0           # streams to the device
@@ -369,17 +370,19 @@ def clear_plan_cache() -> None:
 
 
 # build phase -> (profiler span, PlanCacheStats field it adds to)
-_BUILD_PHASES = {"png": ("repro.plan.png", "png_build_s"),
+_BUILD_PHASES = {"reorder": ("repro.plan.reorder", "reorder_s"),
+                 "png": ("repro.plan.png", "png_build_s"),
                  "schedule": ("repro.plan.schedule", "schedule_build_s"),
                  "upload": ("repro.plan.upload", "upload_s")}
 
 
 @contextlib.contextmanager
 def build_phase(name: str):
-    """One phase of a plan build (``png``, ``schedule``, ``upload``):
-    a ``repro.plan.<name>`` profiler span, its host seconds added to
-    the phase's ``PlanCacheStats`` field.  (``repro.plan.check`` is
-    ``build_plan``'s own: it counts only when the cache misses.)"""
+    """One phase of a plan build (``reorder``, ``png``, ``schedule``,
+    ``upload``): a ``repro.plan.<name>`` profiler span, its host
+    seconds added to the phase's ``PlanCacheStats`` field.
+    (``repro.plan.check`` is ``build_plan``'s own: it counts only when
+    the cache misses.)"""
     span, field = _BUILD_PHASES[name]
     t0 = time.perf_counter()
     try:
@@ -522,11 +525,15 @@ def build_plan(g: Graph, config: PlanConfig | None = None) -> GraphPlan:
         # build every layout on the RELABELED graph (that's the whole
         # point — contiguous hub labels raise PNG compression), but
         # stamp the ORIGINAL graph's fingerprint: the plan belongs to
-        # g, and the reorder name in cfg keeps the cache entry distinct
+        # g, and the reorder name in cfg keeps the cache entry distinct;
+        # the relabeled graph is kept as the plan's internal graph
         from ..graphs.reorder import reorder_permutation
-        perm = reorder_permutation(g, cfg.reorder)
-        plan = get_backend(cfg.method).build_plan(g.relabel(perm), cfg)
+        with build_phase("reorder"):
+            perm = reorder_permutation(g, cfg.reorder)
+            gi = g.relabel(perm)
+        plan = get_backend(cfg.method).build_plan(gi, cfg)
         plan = dataclasses.replace(plan, reorder_perm=perm, graph_fp=fp)
+        plan._device["internal_graph"] = gi
     else:
         plan = get_backend(cfg.method).build_plan(g, cfg)
     if plan.graph_fp is None:

@@ -7,6 +7,7 @@ The paper assumes CSR is given (§VI-D3); we build it once at load time.
 from __future__ import annotations
 
 import dataclasses
+from concurrent.futures import ThreadPoolExecutor
 from functools import cached_property
 
 import numpy as np
@@ -87,9 +88,13 @@ class Graph:
         return jnp.asarray(self.src), jnp.asarray(self.dst)
 
     def relabel(self, perm: np.ndarray) -> "Graph":
-        """Apply a node relabeling: new_id = perm[old_id]."""
+        """Apply a node relabeling: new_id = perm[old_id].  The two
+        endpoint arrays are gathered on threads of their own (numpy
+        releases the GIL for much of a gather)."""
         perm = perm.astype(np.int32)
-        return Graph(self.num_nodes, perm[self.src], perm[self.dst])
+        with ThreadPoolExecutor(2) as pool:
+            src, dst = pool.map(perm.__getitem__, (self.src, self.dst))
+        return Graph(self.num_nodes, src, dst)
 
     def reverse(self) -> "Graph":
         return Graph(self.num_nodes, self.dst, self.src)

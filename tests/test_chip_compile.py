@@ -32,6 +32,9 @@ HBM_BYTES = 16 * 10 ** 9       # one v5e chip
 # update count, arcs padded to whole expand-kernel blocks, and a window
 # of 10240 rows (5.2 MB), two of its 37 partitions of 65536 vertices
 NG22, UG22, MPG22, WG22 = 2_395_582, 24_004_616, 15_664 * 8192, 10_240
+# the same graph relabeled by ``hybrid``: fewer updates, and one
+# partition holding 90 % of the vertices, whose window is the widest
+UH22, WH22 = 10_963_033, 23_680
 
 
 @pytest.fixture(scope="module")
@@ -144,6 +147,23 @@ def test_windowed_expand_compiles_at_graph500_22(one_chip):
     fn = functools.partial(window_expand, window_rows=WG22,
                            interpret=False)
     args = (jax.ShapeDtypeStruct((UG22,), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((MPG22,), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((MPG22 // 8192,), jnp.int32,
+                                 sharding=one_chip))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_windowed_expand_compiles_at_the_hub_window(one_chip):
+    """The relabeled graph's window, about 2.3x the unrelabeled one's
+    and 73 % of the kernel's VMEM budget, compiles within it."""
+    from repro.kernels.pcpm_expand import fits, window_expand
+    from repro.kernels.pcpm_expand.kernel import VMEM_LIMIT, vmem_bytes
+    assert fits(WH22, 8192)
+    assert vmem_bytes(WH22, 8192) > 0.7 * VMEM_LIMIT
+    fn = functools.partial(window_expand, window_rows=WH22,
+                           interpret=False)
+    args = (jax.ShapeDtypeStruct((UH22,), jnp.float32, sharding=one_chip),
             jax.ShapeDtypeStruct((MPG22,), jnp.int32, sharding=one_chip),
             jax.ShapeDtypeStruct((MPG22 // 8192,), jnp.int32,
                                  sharding=one_chip))
