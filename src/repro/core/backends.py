@@ -408,6 +408,20 @@ def _pcpm_device(plan: GraphPlan):
     return dev
 
 
+def _window_rows_device(plan: GraphPlan):
+    """The window kernel's SMEM stream, each arc's window row
+    (``kernels/pcpm_expand.arc_rows``), made on the device from the
+    uploaded indices once per plan, in the ``upload`` phase."""
+    rows = plan._device.get("pcpm_rows")
+    if rows is None:
+        from ..kernels.pcpm_expand import arc_rows
+        _, eui, _, _, _, win = _pcpm_device(plan)
+        with build_phase("upload"):
+            rows = jax.block_until_ready(arc_rows(eui, win))
+        plan._device["pcpm_rows"] = rows
+    return rows
+
+
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
@@ -423,15 +437,16 @@ def window_engages(schedule: GatherSchedule) -> bool:
             and _on_tpu())
 
 
-def _pcpm_pass(upd, eui, ps, pe, pd, win, x, *, num_nodes: int,
-               block: int, window_rows: int, windowed: bool):
+def _pcpm_pass(upd, eui, ps, pe, pd, win, rows, x, *, num_nodes: int,
+               block: int, window_rows: int):
     """The pcpm pass: scatter, expand, reduce.  The expand takes the
-    window kernel where ``windowed`` and ``x`` is one column."""
+    window kernel where the plan bound its row stream ``rows`` (the
+    kernel engages) and ``x`` is one column."""
     from .spmv import (pcpm_expand, pcpm_expand_windowed, pcpm_reduce,
                        pcpm_scatter)
     bins = pcpm_scatter(upd, x)
-    if windowed and x.ndim == 1:
-        vals = pcpm_expand_windowed(bins, eui, win,
+    if rows is not None and x.ndim == 1:
+        vals = pcpm_expand_windowed(bins, eui, win, rows,
                                     window_rows=window_rows)
     else:
         vals = pcpm_expand(bins, eui)
@@ -440,10 +455,10 @@ def _pcpm_pass(upd, eui, ps, pe, pd, win, x, *, num_nodes: int,
 
 def _spmv_pcpm(plan: GraphPlan):
     s = plan.schedule
-    return _streams_fn(_pcpm_pass, *_pcpm_device(plan),
+    rows = _window_rows_device(plan) if window_engages(s) else None
+    return _streams_fn(_pcpm_pass, *_pcpm_device(plan), rows,
                        num_nodes=plan.num_nodes, block=s.block,
-                       window_rows=s.window_rows,
-                       windowed=window_engages(s))
+                       window_rows=s.window_rows)
 
 
 def expand_stats(plan: GraphPlan) -> dict:

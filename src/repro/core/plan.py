@@ -599,11 +599,14 @@ def reorder_inverse(plan: GraphPlan) -> np.ndarray:
 
 
 def plan_nbytes(plan: GraphPlan) -> int:
-    """Host-side footprint of a plan in bytes — the sum of every array
-    ``save`` would persist.  This is what a multi-graph registry's
-    memory budget accounts against (serve/scheduler.py GraphRegistry):
-    the plan streams dominate a resident graph's cost, and unlike
-    device buffers they are exactly enumerable."""
+    """Footprint of a plan in bytes — the sum of every array ``save``
+    would persist, and, where the pcpm window kernel engages, of its
+    row stream (one int32 per arc, ``kernels/pcpm_expand.arc_rows``),
+    which the upload adds.  This is what a multi-graph
+    registry's memory budget accounts against (serve/scheduler.py
+    GraphRegistry): the plan streams dominate a resident graph's cost,
+    and unlike device buffers they are exactly enumerable."""
+    from .backends import window_engages
     arrays: list[np.ndarray] = []
     if plan.reorder_perm is not None:
         arrays.append(plan.reorder_perm)
@@ -621,6 +624,9 @@ def plan_nbytes(plan: GraphPlan) -> int:
                    s.piece_end, s.piece_dst]
         if s.window_start is not None:
             arrays.append(s.window_start)
+        if window_engages(s):
+            # the row stream: one int32 per arc, as the indices
+            arrays.append(s.edge_update_idx_padded)
     if plan.blocked is not None:
         b = plan.blocked
         arrays += [b.update_src, b.edge_update_local, b.edge_dst_local]

@@ -155,15 +155,16 @@ def pcpm_expand(update_bins: jnp.ndarray,
 
 @partial(jax.jit, static_argnames=("window_rows",))
 def pcpm_expand_windowed(update_bins: jnp.ndarray, eui_padded: jnp.ndarray,
-                         window_start: jnp.ndarray, *,
-                         window_rows: int) -> jnp.ndarray:
+                         window_start: jnp.ndarray, arc_rows: jnp.ndarray,
+                         *, window_rows: int) -> jnp.ndarray:
     """``pcpm_expand`` of one rank column by the Pallas kernel that
     reads each arc's update from a VMEM-resident window of its
-    destination partition's bins (``kernels/pcpm_expand``)."""
+    destination partition's bins (``kernels/pcpm_expand``); ``arc_rows``
+    is each arc's window row, made once per plan."""
     from ..kernels.pcpm_expand import window_expand
     with jax.named_scope("pcpm.expand"):
         return window_expand(update_bins, eui_padded, window_start,
-                             window_rows=window_rows)
+                             arc_rows, window_rows=window_rows)
 
 
 @partial(jax.jit, static_argnames=("num_nodes", "block"))

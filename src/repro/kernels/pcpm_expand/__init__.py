@@ -1,3 +1,3 @@
-from .kernel import fits, vmem_bytes, window_expand
+from .kernel import arc_rows, fits, vmem_bytes, window_expand
 
-__all__ = ["fits", "vmem_bytes", "window_expand"]
+__all__ = ["arc_rows", "fits", "vmem_bytes", "window_expand"]

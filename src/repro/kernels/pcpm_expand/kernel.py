@@ -12,15 +12,21 @@ blocks share a window until a block starts in the next partition.
 - A ``(window_rows, 128)`` VMEM scratch holds the block's window.  The
   block's window start row is scalar-prefetched; the window is
   refilled by one DMA only where it differs from the previous block's.
-- Each block's arcs come into SMEM as one word each, the update's
-  window row and lane rotation (``_arc_words``, computed by XLA from
-  the indices).  Per arc: one dynamic row load from the window, the
-  update's lane rotated into the arc's lane, and a select into the
-  output row.
+- Each arc comes in as two streams.  Its update's window row
+  (``arc_rows``, made once per plan) comes into SMEM, so the scalar
+  work per arc is one load and one address.  Its update's lane is the
+  index's low 7 bits, read from the same block of indices in VMEM.
+- Per 128 arcs (one output row): each arc's window row is copied into
+  row ``j`` of a ``(128, 128)`` staging tile.  Then, on whole vregs,
+  the tile's bits are transposed, so that ``t[l, j]`` is lane ``l`` of
+  arc ``j``'s row; every ``t[l, j]`` but ``l == lane[j]`` is zeroed;
+  and the 128 sublanes are folded into one by bitwise OR.  Exactly one
+  word survives per arc and OR with zeros keeps its bits.
 
-The output is the ``(Mp,)`` f32 that ``bins[idx]`` gives, bit for bit.
-The kernel runs compiled on the ``tpu`` platform and in the Pallas
-interpreter on ``cpu`` (``pcpm_spmv.kernel.default_interpret``).
+The output is the ``(Mp,)`` f32 that ``bins[idx]`` gives, bit for bit
+(-0.0, NaN payloads and subnormals included).  The kernel runs compiled
+on the ``tpu`` platform and in the Pallas interpreter on ``cpu``
+(``pcpm_spmv.kernel.default_interpret``).
 """
 from __future__ import annotations
 
@@ -40,17 +46,17 @@ VMEM_LIMIT = 16 * 2 ** 20
 
 
 def vmem_bytes(window_rows: int, block: int) -> int:
-    """The kernel's VMEM working set: the window and the
-    double-buffered output block."""
-    return 4 * (window_rows * LANES + 2 * block)
+    """The kernel's VMEM working set: the window, the staging tile,
+    and the double-buffered blocks of indices and output."""
+    return 4 * (window_rows * LANES + LANES * LANES + 2 * 2 * block)
 
 
 def fits(window_rows: int, block: int) -> bool:
     return vmem_bytes(window_rows, block) <= VMEM_LIMIT
 
 
-def _expand_kernel(start_ref, arc_ref, bins_ref, out_ref, window, sem, *,
-                   window_rows: int):
+def _expand_kernel(start_ref, row_ref, idx_ref, bins_ref, out_ref, window,
+                   stage, sem, *, window_rows: int):
     b = pl.program_id(0)
     start = start_ref[b]
 
@@ -61,37 +67,43 @@ def _expand_kernel(start_ref, arc_ref, bins_ref, out_ref, window, sem, *,
         copy.start()
         copy.wait()
 
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    sublane = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
 
     def row(r, carry):
-        acc = jnp.zeros((1, LANES), jnp.float32)
         for j in range(LANES):
-            a = arc_ref[r, j]
-            v = window[pl.ds(a >> 7, 1), :]
-            v = pltpu.roll(v, a & (LANES - 1), 1)
-            acc = jnp.where(lane == j, v, acc)
-        out_ref[pl.ds(r, 1), :] = acc
+            stage[pl.ds(j, 1), :] = window[pl.ds(row_ref[r, j], 1), :]
+        t = pltpu.bitcast(stage[...], jnp.int32).T
+        lane = idx_ref[pl.ds(r, 1), :] & (LANES - 1)
+        t = jnp.where(sublane == lane, t, 0)
+        acc = t[:SUBLANES]
+        for k in range(SUBLANES, LANES, SUBLANES):
+            acc = acc | t[k:k + SUBLANES]
+        for shift in (4, 2, 1):
+            acc = acc | pltpu.roll(acc, shift, 0)
+        out_ref[pl.ds(r, 1), :] = pltpu.bitcast(acc[:1], jnp.float32)
         return carry
 
-    jax.lax.fori_loop(0, arc_ref.shape[0], row, 0)
+    jax.lax.fori_loop(0, row_ref.shape[0], row, 0)
 
 
-def _arc_words(idx, window_start, block_rows: int):
-    """Per arc, its window row and the lane rotation that moves its
-    update into the arc's own lane, as ``row << 7 | rotation``: the
-    kernel's scalar work per arc is then two bit operations."""
-    idx = idx.reshape(window_start.shape[0], block_rows, LANES)
-    lane = jax.lax.broadcasted_iota(jnp.int32, idx.shape, 2)
-    row = (idx >> 7) - window_start[:, None, None]
-    return ((row << 7) | ((lane - idx) & (LANES - 1))).reshape(-1, LANES)
+@jax.jit
+def arc_rows(idx: jnp.ndarray, window_start: jnp.ndarray) -> jnp.ndarray:
+    """Per arc, the row of its block's window that holds its update,
+    as ``(Mp / 128, 128)`` int32: the kernel's SMEM stream, made once
+    per plan."""
+    rows = (idx.reshape(window_start.shape[0], -1) >> 7
+            ) - window_start[:, None]
+    return rows.reshape(-1, LANES)
 
 
 @functools.partial(jax.jit, static_argnames=("window_rows", "interpret"))
 def window_expand(bins: jnp.ndarray, idx: jnp.ndarray,
-                  window_start: jnp.ndarray, *, window_rows: int,
+                  window_start: jnp.ndarray, rows: jnp.ndarray, *,
+                  window_rows: int,
                   interpret: bool | None = None) -> jnp.ndarray:
     """``bins[idx]`` for ``bins`` (U,) f32 and ``idx`` (Mp,) int32,
-    ``Mp`` a multiple of ``len(window_start)`` whole 128-lane rows.
+    ``Mp`` a multiple of ``len(window_start)`` whole 128-lane rows;
+    ``rows`` is ``arc_rows(idx, window_start)``.
 
     ``window_start[b]`` is the first bin row of block ``b``'s window:
     every index of the block lies in rows ``[window_start[b],
@@ -104,10 +116,11 @@ def window_expand(bins: jnp.ndarray, idx: jnp.ndarray,
     nblocks = window_start.shape[0]
     block_rows = mp // (nblocks * LANES)
     assert block_rows * nblocks * LANES == mp, (mp, nblocks)
-    rows = -(-max(-(-num_updates // LANES), 1) // SUBLANES) * SUBLANES
-    assert window_rows % SUBLANES == 0 and window_rows <= rows, (
-        window_rows, rows)
-    bins = jnp.pad(bins, (0, rows * LANES - num_updates))
+    assert rows.shape == (mp // LANES, LANES), (rows.shape, mp)
+    bin_rows = -(-max(-(-num_updates // LANES), 1) // SUBLANES) * SUBLANES
+    assert window_rows % SUBLANES == 0 and window_rows <= bin_rows, (
+        window_rows, bin_rows)
+    bins = jnp.pad(bins, (0, bin_rows * LANES - num_updates))
     kernel = functools.partial(_expand_kernel, window_rows=window_rows)
     out = pl.pallas_call(
         kernel,
@@ -117,11 +130,13 @@ def window_expand(bins: jnp.ndarray, idx: jnp.ndarray,
             in_specs=[
                 pl.BlockSpec((block_rows, LANES), lambda b, s: (b, 0),
                              memory_space=pltpu.SMEM),
+                pl.BlockSpec((block_rows, LANES), lambda b, s: (b, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((block_rows, LANES),
                                    lambda b, s: (b, 0)),
             scratch_shapes=[pltpu.VMEM((window_rows, LANES), jnp.float32),
+                            pltpu.VMEM((LANES, LANES), jnp.float32),
                             pltpu.SemaphoreType.DMA(())],
         ),
         out_shape=jax.ShapeDtypeStruct((mp // LANES, LANES), jnp.float32),
@@ -129,6 +144,6 @@ def window_expand(bins: jnp.ndarray, idx: jnp.ndarray,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(window_start, _arc_words(idx, window_start, block_rows),
-      bins.reshape(rows, LANES))
+    )(window_start, rows, idx.reshape(-1, LANES),
+      bins.reshape(bin_rows, LANES))
     return out.reshape(mp)

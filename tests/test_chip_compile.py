@@ -149,6 +149,8 @@ def test_windowed_expand_compiles_at_graph500_22(one_chip):
     args = (jax.ShapeDtypeStruct((UG22,), jnp.float32, sharding=one_chip),
             jax.ShapeDtypeStruct((MPG22,), jnp.int32, sharding=one_chip),
             jax.ShapeDtypeStruct((MPG22 // 8192,), jnp.int32,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((MPG22 // LANES, LANES), jnp.int32,
                                  sharding=one_chip))
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -166,6 +168,8 @@ def test_windowed_expand_compiles_at_the_hub_window(one_chip):
     args = (jax.ShapeDtypeStruct((UH22,), jnp.float32, sharding=one_chip),
             jax.ShapeDtypeStruct((MPG22,), jnp.int32, sharding=one_chip),
             jax.ShapeDtypeStruct((MPG22 // 8192,), jnp.int32,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((MPG22 // LANES, LANES), jnp.int32,
                                  sharding=one_chip))
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -202,16 +206,15 @@ def test_fused_loop_holds_window_kernel_under_expand(one_chip, monkeypatch):
     from repro.core import backends
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
-    def i32(n):
-        return np.broadcast_to(np.int32(0), (n,))
+    def i32(*shape):
+        return np.broadcast_to(np.int32(0), shape)
 
     pieces = NG22 + MPG22 // DEFAULT_GATHER_BLOCK
     spmv = jax.tree_util.Partial(
         functools.partial(backends._pcpm_pass, num_nodes=NG22,
-                          block=DEFAULT_GATHER_BLOCK, window_rows=WG22,
-                          windowed=True),
+                          block=DEFAULT_GATHER_BLOCK, window_rows=WG22),
         i32(UG22), i32(MPG22), i32(pieces), i32(pieces), i32(pieces),
-        i32(MPG22 // 8192))
+        i32(MPG22 // 8192), i32(MPG22 // LANES, LANES))
     (call,) = _custom_calls(_fused_loop_text(spmv, NG22, one_chip))
     assert "/pcpm.expand/" in call
 

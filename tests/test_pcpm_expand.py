@@ -6,7 +6,8 @@ import jax.numpy as jnp
 
 from repro.core import Partitioning, build_gather_schedule, build_png
 from repro.graphs.formats import Graph
-from repro.kernels.pcpm_expand import fits, vmem_bytes, window_expand
+from repro.kernels.pcpm_expand import (arc_rows, fits, vmem_bytes,
+                                       window_expand)
 
 KB = 1024                     # arcs per kernel block: one (8, 128) tile
 
@@ -25,6 +26,36 @@ def _blocks(rng, num_updates, window_rows, starts, pad=()):
             np.asarray(starts, np.int32))
 
 
+def _special_bins(rng, num_updates):
+    """Bins whose bits a float sum or a max would not keep: -0.0,
+    infinities, subnormals and NaNs with payloads, among others."""
+    bins = rng.standard_normal(num_updates).astype(np.float32)
+    bits = bins.view(np.int32)
+    special = np.array([0x80000000, 0x7F800000, 0xFF800000, 0x00000001,
+                        0x807FFFFF, 0x7FC01234, 0xFFBADBAD, 0x7F800001],
+                       np.uint32).view(np.int32)
+    bits[::3] = np.resize(special, bits[::3].shape)
+    return bins
+
+
+def _one_row_per_output_row(rng, num_updates, window_rows, starts):
+    """Every arc of a 128-arc output row reads the same window row."""
+    idx, win = _blocks(rng, num_updates, window_rows, starts)
+    rows = idx.reshape(-1, 128)
+    rows[:] = (rows[:, :1] // 128) * 128 + rng.integers(0, 128,
+                                                        rows.shape)
+    return idx, win
+
+
+def _arc_j_reads_lane_j(rng, num_updates, window_rows, starts):
+    """Arc ``j`` of each output row reads lane ``j`` of a row drawn
+    from its window: the staging tile's diagonal."""
+    idx, win = _blocks(rng, num_updates, window_rows, starts)
+    rows = idx.reshape(-1, 128)
+    rows[:] = (rows // 128) * 128 + np.arange(128)
+    return idx, win
+
+
 CASES = {
     # U, window rows, window start per block, pure-padding blocks;
     # windows are whole (8, 128) tiles
@@ -34,6 +65,9 @@ CASES = {
     "pure_padding_block": (2000, 8, [0, 8, 8, 8], (1, 3)),
     # every update in one 128-lane row
     "one_row_of_updates": (100, 8, [0, 0], ()),
+    "special_values": (3000, 16, [0, 8, 8], ()),
+    "one_window_row_per_output_row": (4096, 16, [0, 8, 16], ()),
+    "arc_j_reads_lane_j": (4096, 16, [0, 8, 16], ()),
 }
 
 
@@ -41,11 +75,32 @@ CASES = {
 def test_kernel_equals_xla_gather(case):
     num_updates, rows, starts, pad = CASES[case]
     rng = np.random.default_rng(len(case))
-    idx, win = _blocks(rng, num_updates, rows, starts, pad)
-    bins = rng.random(num_updates, dtype=np.float32)
-    out = window_expand(jnp.asarray(bins), jnp.asarray(idx),
-                        jnp.asarray(win), window_rows=rows)
-    np.testing.assert_array_equal(np.asarray(out), bins[idx])
+    make = {"one_window_row_per_output_row": _one_row_per_output_row,
+            "arc_j_reads_lane_j": _arc_j_reads_lane_j}.get(case)
+    if make is None:
+        idx, win = _blocks(rng, num_updates, rows, starts, pad)
+    else:
+        idx, win = make(rng, num_updates, rows, starts)
+    bins = (_special_bins(rng, num_updates) if case == "special_values"
+            else rng.random(num_updates, dtype=np.float32))
+    idx_d, win_d = jnp.asarray(idx), jnp.asarray(win)
+    out = window_expand(jnp.asarray(bins), idx_d, win_d,
+                        arc_rows(idx_d, win_d), window_rows=rows)
+    np.testing.assert_array_equal(np.asarray(out).view(np.int32),
+                                  bins[idx].view(np.int32))
+
+
+def test_arc_rows_are_window_rows():
+    """Each arc's row in its block's window, one row of 128 arcs per
+    block here."""
+    rng = np.random.default_rng(1)
+    idx = np.concatenate([rng.integers(0, 1024, 128),
+                          rng.integers(1024, 2048, 128)]).astype(np.int32)
+    win = np.array([0, 8], np.int32)
+    got = np.asarray(arc_rows(jnp.asarray(idx), jnp.asarray(win)))
+    assert got.shape == (2, 128)
+    np.testing.assert_array_equal(got.reshape(-1),
+                                  idx // 128 - np.repeat(win, 128))
 
 
 def test_kernel_on_a_schedule_with_an_empty_partition():
@@ -60,16 +115,22 @@ def test_kernel_on_a_schedule_with_an_empty_partition():
     sched = build_gather_schedule(layout)
     assert sched.kernel_block == 8192
     bins = rng.random(layout.num_updates, dtype=np.float32)
-    eui = sched.edge_update_idx_padded
-    out = window_expand(jnp.asarray(bins), jnp.asarray(eui),
-                        jnp.asarray(sched.window_start),
+    eui = jnp.asarray(sched.edge_update_idx_padded)
+    win = jnp.asarray(sched.window_start)
+    out = window_expand(jnp.asarray(bins), eui, win, arc_rows(eui, win),
                         window_rows=sched.window_rows)
-    np.testing.assert_array_equal(np.asarray(out), bins[eui])
+    np.testing.assert_array_equal(np.asarray(out),
+                                  bins[sched.edge_update_idx_padded])
 
 
 def test_vmem_budget():
-    assert vmem_bytes(1000, 8192) == 4 * (1000 * 128 + 2 * 8192)
-    assert fits(10240, 8192)                  # graph500-22's window
+    """The window, the (128, 128) staging tile, and the double-buffered
+    blocks of indices and output."""
+    assert vmem_bytes(1000, 8192) == 4 * (1000 * 128 + 128 * 128
+                                          + 4 * 8192)
+    assert fits(10464, 8192)                  # graph500-22's window
+    assert fits(10240, 8192)
+    assert fits(23680, 8192)                  # its relabeled hub window
     assert not fits(33000, 8192)              # 16.9 MB of window
 
 
